@@ -9,7 +9,9 @@ once + (grp, hd) out; the XLA reference materializes (grp, S) scores and
 Grid = (B·Hkv, S/block_s), cache-block dim minormost so the (grp, hd)
 accumulator persists in VMEM scratch across cache blocks. Invalid slots
 (beyond ``cache_len``, e.g. unwritten ring-buffer entries) are masked via
-a per-row length input.
+a per-row length that is scalar-prefetched into SMEM: Mosaic accepts a
+rank-1 VMEM block only when it spans the array or a multiple of 128, so
+the lengths cannot be tiled ``(1,)`` per row.
 """
 
 from __future__ import annotations
@@ -41,7 +43,7 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
     kpos = si * block_s + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    valid = kpos < len_ref[0]
+    valid = kpos < len_ref[pl.program_id(0)]
     s = jnp.where(valid, s, NEG_INF)
 
     m_prev = m_scr[...]
@@ -72,21 +74,25 @@ def flash_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     scale = 1.0 / math.sqrt(hd)
     kernel = functools.partial(_decode_kernel, scale=scale, block_s=block_s,
                                n_s_blocks=n_s)
-    return pl.pallas_call(
-        kernel,
+    # index maps take the prefetched lengths as a trailing argument
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(bhkv, n_s),
         in_specs=[
-            pl.BlockSpec((1,), lambda b, j: (b,)),
-            pl.BlockSpec((1, grp, hd), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, block_s, hd), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, block_s, hd), lambda b, j: (b, j, 0)),
+            pl.BlockSpec((1, grp, hd), lambda b, j, lens: (b, 0, 0)),
+            pl.BlockSpec((1, block_s, hd), lambda b, j, lens: (b, j, 0)),
+            pl.BlockSpec((1, block_s, hd), lambda b, j, lens: (b, j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, grp, hd), lambda b, j: (b, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((bhkv, grp, hd), q.dtype),
+        out_specs=pl.BlockSpec((1, grp, hd), lambda b, j, lens: (b, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((grp, 1), jnp.float32),
             pltpu.VMEM((grp, 1), jnp.float32),
             pltpu.VMEM((grp, hd), jnp.float32),
         ],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((bhkv, grp, hd), q.dtype),
         interpret=interpret,
     )(cache_len.astype(jnp.int32), q, k_cache, v_cache)

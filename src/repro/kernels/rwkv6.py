@@ -30,7 +30,7 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, y_ref, state_scr, *,
     def _init():
         state_scr[...] = jnp.zeros_like(state_scr)
 
-    u = u_ref[0]                                           # (hd,)
+    u = u_ref[0, 0]                                        # (hd,)
 
     def step(t, state):
         r = r_ref[0, t, :]                                 # (hd,)
@@ -57,13 +57,16 @@ def wkv6_chunked(r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,
 
     kernel = functools.partial(_wkv_kernel, chunk=chunk)
     seq_spec = pl.BlockSpec((1, chunk, hd), lambda b, c: (b, c, 0))
+    # u as (BH, 1, hd): a (1, hd) block over (BH, hd) breaks the TPU
+    # tiling rule (second-to-last block dim divisible by 8 or full)
+    u_spec = pl.BlockSpec((1, 1, hd), lambda b, c: (b, 0, 0))
     return pl.pallas_call(
         kernel,
         grid=(bh, n_chunks),
         in_specs=[seq_spec, seq_spec, seq_spec, seq_spec,
-                  pl.BlockSpec((1, hd), lambda b, c: (b, 0))],
+                  u_spec],
         out_specs=seq_spec,
         out_shape=jax.ShapeDtypeStruct((bh, s, hd), jnp.float32),
         scratch_shapes=[pltpu.VMEM((hd, hd), jnp.float32)],
         interpret=interpret,
-    )(r, k, v, w, u)
+    )(r, k, v, w, u[:, None, :])

@@ -38,6 +38,7 @@ from ..train.checkpoint import restore_checkpoint, save_checkpoint
 from ..train.data import DataIterator
 from ..train.optimizer import OptConfig
 from ..train.train_step import init_train_state, train_step
+from .runtime import describe_devices, enable_compile_cache
 
 PRESETS = {
     "tiny": ArchConfig(
@@ -126,6 +127,8 @@ def main() -> None:
     ap.add_argument("--ckpt-every", type=int, default=20)
     ap.add_argument("--failover-at", type=int, default=None)
     args = ap.parse_args()
+    enable_compile_cache()
+    print(f"[train] devices: {describe_devices()}")
 
     if args.preset:
         cfg = PRESETS[args.preset]

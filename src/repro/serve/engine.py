@@ -48,8 +48,12 @@ class Engine:
         self._decode = jax.jit(partial(decode_step, cfg=self.cfg))
 
     def generate(self, tokens: jax.Array,
-                 max_new_tokens: Optional[int] = None) -> np.ndarray:
-        """tokens: (B, S) prompt batch -> (B, new) generated ids."""
+                 max_new_tokens: Optional[int] = None,
+                 return_logits: bool = False):
+        """tokens: (B, S) prompt batch -> (B, new) generated ids. With
+        ``return_logits``, also the (B, new, V) float32 logits each id
+        was sampled from: index 0 from prefill, index i from the i-th
+        decode step."""
         cfg = self.cfg
         b, s = tokens.shape
         n_new = max_new_tokens or self.scfg.max_new_tokens
@@ -63,17 +67,24 @@ class Engine:
                     return jnp.pad(c, pad)
                 return c
             caches = jax.tree.map(grow, caches)
-        out = []
+        out, seen = [], []
         key = jax.random.PRNGKey(self.scfg.seed)
         tok = self._sample(logits, key)
         out.append(tok)
+        if return_logits:
+            seen.append(logits)
         for i in range(n_new - 1):
             logits, caches = decode_step(self.params, cfg, tok, caches,
                                          pos + i)
             key = jax.random.fold_in(key, i)
             tok = self._sample(logits, key)
             out.append(tok)
-        return np.stack([np.asarray(t) for t in out], axis=1)
+            if return_logits:
+                seen.append(logits)
+        ids = np.stack([np.asarray(t) for t in out], axis=1)
+        if return_logits:
+            return ids, np.stack([np.asarray(l) for l in seen], axis=1)
+        return ids
 
     def _sample(self, logits: jax.Array, key) -> jax.Array:
         if self.scfg.temperature <= 0.0:

@@ -47,8 +47,6 @@ for fam, name in sorted(fams.items()):
         lowered = lower_cell(cfg, shape, mesh)
         compiled = lowered.compile()
         cost = compiled.cost_analysis()
-        if isinstance(cost, list):   # older jax: one dict per device
-            cost = cost[0] if cost else {}
         assert cost.get("flops", 0) >= 0
         results[f"{fam}:{kind}"] = True
 print("DRYRUN_OK " + json.dumps(results))

@@ -1,0 +1,111 @@
+"""Compile-only tests for one TPU v5e chip, described and not attached.
+
+The TPU compiler refuses what interpret mode accepts: block shapes that
+break the tiling, programs that do not fit the chip's memory. These tests
+compile the Pallas kernels at the widths of the configured models, and
+the full-width h2o-danube-1.8b decode step, for a chip of a described
+``v5e:2x2`` topology. Nothing runs, so they say nothing about results or
+times.
+
+The topology is described inside a module-scoped fixture, never at
+import time: only one process may load the TPU library, and test
+collection must not depend on whether it did.
+"""
+
+import os
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_arch
+from repro.kernels.decode_attention import flash_decode
+from repro.kernels.flash_attention import flash_attention_fwd
+from repro.kernels.rwkv6 import wkv6_chunked
+from repro.models import decode_step, init_decode_cache, init_params
+
+V5E_HBM_BYTES = 16 * 2**30
+
+BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
+
+# (kernel call, argument (shape, dtype) pairs). Batch 1 at a 4096-token
+# sequence or cache; widths from configs/: danube hd 80, 32 q / 8 kv
+# heads, window 4096; qwen2.5-3b hd 128, 16 q / 2 kv heads; rwkv6-3b
+# 40 heads of hd 64.
+KERNEL_CASES = {
+    "flash_attention_fwd-danube": (
+        partial(flash_attention_fwd, window=4096),
+        [((32, 4096, 80), BF16), ((8, 4096, 80), BF16),
+         ((8, 4096, 80), BF16)]),
+    "flash_attention_fwd-qwen2.5-3b": (
+        flash_attention_fwd,
+        [((16, 4096, 128), BF16), ((2, 4096, 128), BF16),
+         ((2, 4096, 128), BF16)]),
+    "flash_decode-danube": (
+        flash_decode,
+        [((8, 4, 80), BF16), ((8, 4096, 80), BF16), ((8, 4096, 80), BF16),
+         ((8,), I32)]),
+    "flash_decode-qwen2.5-3b": (
+        flash_decode,
+        [((2, 8, 128), BF16), ((2, 4096, 128), BF16),
+         ((2, 4096, 128), BF16), ((2,), I32)]),
+    "wkv6_chunked-rwkv6-3b": (
+        wkv6_chunked,
+        [((40, 512, 64), F32)] * 4 + [((40, 64), F32)]),
+}
+
+
+@pytest.fixture(scope="module")
+def topo():
+    # keep the compiler's logs out of the shared temp directory
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler installed, or it cannot load
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """One described chip, with the persistent compilation cache off: a
+    program compiled for an absent chip is written to the cache but can
+    never be read back."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was_enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was_enabled)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("case", sorted(KERNEL_CASES))
+def test_kernel_compiles_for_v5e(case, one_chip):
+    fn, args = KERNEL_CASES[case]
+    shapes = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in args]
+    compiled = jax.jit(fn).lower(*shapes).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_danube_decode_step_fits_one_v5e(one_chip):
+    cfg = get_arch("h2o-danube-1.8b")
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(
+        partial(init_params, jax.random.PRNGKey(0), cfg)))
+    caches = on_chip(jax.eval_shape(
+        partial(init_decode_cache, cfg, 4, cfg.sliding_window)))
+    ids = jax.ShapeDtypeStruct((4,), jnp.int32, sharding=one_chip)
+    step = jax.jit(lambda p, tok, c, pos: decode_step(p, cfg, tok, c, pos))
+    compiled = step.lower(params, ids, caches, ids).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes > 3.5e9   # the full bf16 weights
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        < V5E_HBM_BYTES

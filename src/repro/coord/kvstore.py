@@ -165,6 +165,14 @@ class CoordClient:
                 "retries": self.retries}
 
 
+def _span(name: str, **kwargs):
+    """A profiler span over a wall-clock coordinator call. JAX is loaded
+    here and not at the top: the simulator never builds a
+    LocalCoordinator and runs without it."""
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation(name, **kwargs)
+
+
 class LocalCoordinator:
     """Replicated, linearizable KV (append-only lists per key) with
     LeaseGuard zero-roundtrip reads by default; any policy from the
@@ -180,6 +188,10 @@ class LocalCoordinator:
         sim = SimParams(seed=seed)
         self.cluster: Cluster = build_cluster(raft, sim)
         self.cluster.wait_for_leader()
+        # operations that succeeded, and the network messages sent while
+        # their successful attempt ran
+        self.appends = 0
+        self.append_messages = 0
         self.reads = 0
         self.read_messages = 0
 
@@ -207,28 +219,35 @@ class LocalCoordinator:
     # -- public KV API ----------------------------------------------------
     def append(self, key: str, value: Any, retries: int = 5) -> None:
         """Linearizable durable write (committed through the Raft log)."""
-        payload = json.dumps(value)
-        for _ in range(retries):
-            ldr = self._leader()
-            res = self._run(ldr.client_write(key, payload))
-            if res.ok:
-                return
-            # not_leader / no_lease / timeout: crank forward and retry
-            self.cluster.loop.run_until(self.cluster.loop.now + 0.3)
-        raise CoordinatorError(f"write failed after {retries} retries")
+        with _span("coord.append", key=key):
+            payload = json.dumps(value)
+            for _ in range(retries):
+                ldr = self._leader()
+                before = self.cluster.net.messages_sent
+                res = self._run(ldr.client_write(key, payload))
+                if res.ok:
+                    self.appends += 1
+                    self.append_messages += (self.cluster.net.messages_sent
+                                             - before)
+                    return
+                # not_leader / no_lease / timeout: crank forward and retry
+                self.cluster.loop.run_until(self.cluster.loop.now + 0.3)
+            raise CoordinatorError(f"write failed after {retries} retries")
 
     def read_list(self, key: str, retries: int = 5) -> list:
         """Linearizable read — zero network roundtrips under LeaseGuard."""
-        for _ in range(retries):
-            ldr = self._leader()
-            before = self.cluster.net.messages_sent
-            res = self._run(ldr.client_read(key))
-            if res.ok:
-                self.reads += 1
-                self.read_messages += self.cluster.net.messages_sent - before
-                return [json.loads(v) for v in res.value]
-            self.cluster.loop.run_until(self.cluster.loop.now + 0.3)
-        raise CoordinatorError(f"read failed after {retries} retries")
+        with _span("coord.read", key=key):
+            for _ in range(retries):
+                ldr = self._leader()
+                before = self.cluster.net.messages_sent
+                res = self._run(ldr.client_read(key))
+                if res.ok:
+                    self.reads += 1
+                    self.read_messages += (self.cluster.net.messages_sent
+                                           - before)
+                    return [json.loads(v) for v in res.value]
+                self.cluster.loop.run_until(self.cluster.loop.now + 0.3)
+            raise CoordinatorError(f"read failed after {retries} retries")
 
     def read_latest(self, key: str) -> Optional[Any]:
         xs = self.read_list(key)
@@ -305,6 +324,8 @@ class LocalCoordinator:
     def stats(self) -> dict:
         return {
             "consistency": self.read_mode.value,
+            "appends": self.appends,
+            "append_messages": self.append_messages,
             "reads": self.reads,
             "read_messages": self.read_messages,
             "messages_total": self.cluster.net.messages_sent,

@@ -29,6 +29,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from ..configs import get_arch
 from ..configs.base import ArchConfig, ShapeConfig
@@ -84,28 +85,34 @@ def run_training(cfg: ArchConfig, shape: ShapeConfig, steps: int,
 
     losses = []
     for step in range(start_step, steps):
-        batch = {k: jnp.asarray(v) for k, v in next(data).items()}
-        t0 = time.time()
-        state, metrics = step_fn(state, batch)
-        loss = float(metrics["loss"])
-        dt = time.time() - t0
-        losses.append(loss)
-        registry.report_step_time(worker_id, step, dt)
-        registry.heartbeat(worker_id)   # feeds live_workers(ttl=...)
-        if failover_at is not None and step == failover_at:
-            crashed = registry.coord.crash_leader()
-            print(f"[train] coordinator leader {crashed} crashed at step "
-                  f"{step}; training continues through failover")
-        if step % log_every == 0 or step == steps - 1:
-            print(f"[train] step {step:5d} loss {loss:.4f} "
-                  f"({dt:.2f}s)", flush=True)
-        if (step + 1) % ckpt_every == 0 or step == steps - 1:
-            manifest = save_checkpoint(
-                ckpt_dir, step + 1, state,
-                extra={"arch": cfg.name, "data": data.state()},
-                registry=registry)
-            print(f"[train] checkpoint step {step+1} committed via Raft "
-                  f"(sha {manifest['sha256'][:10]})")
+        # profiler spans: the step and its host phases (no device work)
+        with StepTraceAnnotation("train.step", step_num=step):
+            with TraceAnnotation("train.batch"):
+                batch = {k: jnp.asarray(v) for k, v in next(data).items()}
+            t0 = time.time()
+            with TraceAnnotation("train.dispatch"):
+                state, metrics = step_fn(state, batch)
+            with TraceAnnotation("train.loss_wait"):
+                loss = float(metrics["loss"])
+            dt = time.time() - t0
+            losses.append(loss)
+            with TraceAnnotation("train.report"):
+                registry.report_step_time(worker_id, step, dt)
+                registry.heartbeat(worker_id)  # feeds live_workers(ttl=...)
+            if failover_at is not None and step == failover_at:
+                crashed = registry.coord.crash_leader()
+                print(f"[train] coordinator leader {crashed} crashed at "
+                      f"step {step}; training continues through failover")
+            if step % log_every == 0 or step == steps - 1:
+                print(f"[train] step {step:5d} loss {loss:.4f} "
+                      f"({dt:.2f}s)", flush=True)
+            if (step + 1) % ckpt_every == 0 or step == steps - 1:
+                manifest = save_checkpoint(
+                    ckpt_dir, step + 1, state,
+                    extra={"arch": cfg.name, "data": data.state()},
+                    registry=registry)
+                print(f"[train] checkpoint step {step+1} committed via "
+                      f"Raft (sha {manifest['sha256'][:10]})")
     stats = registry.coord.stats()
     print(f"[train] coordinator stats: {stats}")
     flags = registry.straggler_flags()

@@ -12,6 +12,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from ..configs.base import ArchConfig
 from ..models import decode_step, init_decode_cache, prefill
@@ -53,41 +54,57 @@ class Engine:
         """tokens: (B, S) prompt batch -> (B, new) generated ids. With
         ``return_logits``, also the (B, new, V) float32 logits each id
         was sampled from: index 0 from prefill, index i from the i-th
-        decode step."""
+        decode step.
+
+        Each phase runs under a profiler span (``engine.generate`` over
+        ``engine.prefill``, ``engine.grow_cache``, ``engine.sample``, one
+        ``engine.decode_step`` per further token and ``engine.fetch``),
+        on the clock of the device trace when the profiler runs; the
+        spans add no device work and no synchronisation."""
         cfg = self.cfg
         b, s = tokens.shape
         n_new = max_new_tokens or self.scfg.max_new_tokens
-        logits, caches, pos = prefill(self.params, cfg, {"tokens": tokens})
-        # grow KV caches to hold the generated tokens
-        if not cfg.attn_free:
-            def grow(c):
-                if c.ndim == 5:   # (L, B, S, Hkv, hd)
-                    pad = [(0, 0)] * 5
-                    pad[2] = (0, n_new)
-                    return jnp.pad(c, pad)
-                return c
-            caches = jax.tree.map(grow, caches)
-        out, seen = [], []
-        key = jax.random.PRNGKey(self.scfg.seed)
-        tok = self._sample(logits, key)
-        out.append(tok)
-        if return_logits:
-            seen.append(logits)
-        for i in range(n_new - 1):
-            logits, caches = decode_step(self.params, cfg, tok, caches,
-                                         pos + i)
-            key = jax.random.fold_in(key, i)
+        with TraceAnnotation("engine.generate", batch=b, prompt_len=s,
+                             new_tokens=n_new):
+            with TraceAnnotation("engine.prefill"):
+                logits, caches, pos = prefill(self.params, cfg,
+                                              {"tokens": tokens})
+            # grow KV caches to hold the generated tokens
+            if not cfg.attn_free:
+                def grow(c):
+                    if c.ndim == 5:   # (L, B, S, Hkv, hd)
+                        pad = [(0, 0)] * 5
+                        pad[2] = (0, n_new)
+                        return jnp.pad(c, pad)
+                    return c
+                with TraceAnnotation("engine.grow_cache"):
+                    caches = jax.tree.map(grow, caches)
+            out, seen = [], []
+            key = jax.random.PRNGKey(self.scfg.seed)
             tok = self._sample(logits, key)
             out.append(tok)
             if return_logits:
                 seen.append(logits)
-        ids = np.stack([np.asarray(t) for t in out], axis=1)
-        if return_logits:
-            return ids, np.stack([np.asarray(l) for l in seen], axis=1)
-        return ids
+            for i in range(n_new - 1):
+                with StepTraceAnnotation("engine.decode_step", step_num=i):
+                    logits, caches = decode_step(self.params, cfg, tok,
+                                                 caches, pos + i)
+                    key = jax.random.fold_in(key, i)
+                    tok = self._sample(logits, key)
+                out.append(tok)
+                if return_logits:
+                    seen.append(logits)
+            with TraceAnnotation("engine.fetch"):
+                ids = np.stack([np.asarray(t) for t in out], axis=1)
+                if return_logits:
+                    return ids, np.stack([np.asarray(l) for l in seen],
+                                         axis=1)
+            return ids
 
     def _sample(self, logits: jax.Array, key) -> jax.Array:
-        if self.scfg.temperature <= 0.0:
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return jax.random.categorical(
-            key, logits / self.scfg.temperature, axis=-1).astype(jnp.int32)
+        with TraceAnnotation("engine.sample"):
+            if self.scfg.temperature <= 0.0:
+                return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            return jax.random.categorical(
+                key, logits / self.scfg.temperature,
+                axis=-1).astype(jnp.int32)

@@ -21,6 +21,7 @@ from typing import Any, Optional
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 
 def _flatten(tree: Any) -> dict[str, np.ndarray]:
@@ -37,34 +38,47 @@ def _flatten(tree: Any) -> dict[str, np.ndarray]:
     return flat
 
 
+def _sha256(path: str) -> str:
+    digest = hashlib.sha256()
+    with open(path, "rb") as f:
+        for chunk in iter(lambda: f.read(1 << 20), b""):
+            digest.update(chunk)
+    return digest.hexdigest()
+
+
 def save_checkpoint(directory: str, step: int, state: Any,
                     extra: Optional[dict] = None,
                     registry=None) -> dict:
     """Write arrays + manifest; commit the manifest via the registry
-    (LeaseGuard Raft) if one is provided. Returns the manifest."""
-    path = os.path.join(directory, f"step_{step}")
-    os.makedirs(path, exist_ok=True)
-    flat = _flatten(state)
-    npz_path = os.path.join(path, "arrays.npz")
-    np.savez(npz_path, **flat)
-    digest = hashlib.sha256()
-    with open(npz_path, "rb") as f:
-        for chunk in iter(lambda: f.read(1 << 20), b""):
-            digest.update(chunk)
-    manifest = {
-        "step": step,
-        "path": path,
-        "n_arrays": len(flat),
-        "sha256": digest.hexdigest(),
-        "extra": extra or {},
-    }
-    with open(os.path.join(path, "manifest.json"), "w") as f:
-        json.dump(manifest, f, indent=1)
-    if registry is not None:
-        res = registry.commit_checkpoint(manifest)
-        if not res:
-            raise RuntimeError("coordinator rejected checkpoint commit")
-    return manifest
+    (LeaseGuard Raft) if one is provided. Returns the manifest. Runs
+    under the profiler span ``ckpt.save``, split into ``ckpt.copy``,
+    ``ckpt.write``, ``ckpt.hash`` and ``ckpt.commit``."""
+    with TraceAnnotation("ckpt.save", step=step):
+        path = os.path.join(directory, f"step_{step}")
+        os.makedirs(path, exist_ok=True)
+        with TraceAnnotation("ckpt.copy"):
+            flat = _flatten(state)
+        npz_path = os.path.join(path, "arrays.npz")
+        with TraceAnnotation("ckpt.write"):
+            np.savez(npz_path, **flat)
+        with TraceAnnotation("ckpt.hash"):
+            sha256 = _sha256(npz_path)
+        manifest = {
+            "step": step,
+            "path": path,
+            "n_arrays": len(flat),
+            "sha256": sha256,
+            "extra": extra or {},
+        }
+        with TraceAnnotation("ckpt.commit"):
+            with open(os.path.join(path, "manifest.json"), "w") as f:
+                json.dump(manifest, f, indent=1)
+            if registry is not None:
+                res = registry.commit_checkpoint(manifest)
+                if not res:
+                    raise RuntimeError(
+                        "coordinator rejected checkpoint commit")
+        return manifest
 
 
 def restore_checkpoint(state_template: Any, manifest: dict) -> Any:
@@ -84,8 +98,4 @@ def verify_checkpoint(manifest: dict) -> bool:
     npz_path = os.path.join(manifest["path"], "arrays.npz")
     if not os.path.exists(npz_path):
         return False
-    digest = hashlib.sha256()
-    with open(npz_path, "rb") as f:
-        for chunk in iter(lambda: f.read(1 << 20), b""):
-            digest.update(chunk)
-    return digest.hexdigest() == manifest["sha256"]
+    return _sha256(npz_path) == manifest["sha256"]

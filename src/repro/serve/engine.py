@@ -5,8 +5,7 @@ zero-roundtrip reads."""
 
 from __future__ import annotations
 
-from dataclasses import dataclass, field
-from functools import partial
+from dataclasses import dataclass
 from typing import Optional
 
 import jax
@@ -15,7 +14,7 @@ import numpy as np
 from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from ..configs.base import ArchConfig
-from ..models import decode_step, init_decode_cache, prefill
+from ..models import decode_step, prefill
 
 
 @dataclass
@@ -46,7 +45,28 @@ class Engine:
         if registry is not None:
             # leased read: which checkpoint should we be serving?
             self.model_version = registry.latest_checkpoint()
-        self._decode = jax.jit(partial(decode_step, cfg=self.cfg))
+        # One compiled program per phase and shape, built once. The decode
+        # step also advances the positions, and its new caches take the
+        # buffers of the caches it is given. Every program in flight holds
+        # one of the device queue's slots, so a step issues two (decode
+        # and sample) and the host runs that much further ahead.
+        temperature = serve_cfg.temperature
+
+        def serve_prefill(params, tokens):
+            return prefill(params, cfg, {"tokens": tokens})
+
+        def serve_decode(params, tok, caches, pos):
+            return (*decode_step(params, cfg, tok, caches, pos), pos + 1)
+
+        def serve_sample(logits, key):
+            if temperature <= 0.0:
+                return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            return jax.random.categorical(key, logits / temperature,
+                                          axis=-1).astype(jnp.int32)
+
+        self._prefill = jax.jit(serve_prefill)
+        self._decode = jax.jit(serve_decode, donate_argnums=2)
+        self._pick = jax.jit(serve_sample)
 
     def generate(self, tokens: jax.Array,
                  max_new_tokens: Optional[int] = None,
@@ -60,15 +80,19 @@ class Engine:
         ``engine.prefill``, ``engine.grow_cache``, ``engine.sample``, one
         ``engine.decode_step`` per further token and ``engine.fetch``),
         on the clock of the device trace when the profiler runs; the
-        spans add no device work and no synchronisation."""
+        spans add no device work and no synchronisation.
+
+        Prefill, each decode step and each sample run as compiled
+        programs, so after the first batch of a shape nothing is traced
+        or compiled again. Nothing is read from the device before the
+        fetch: a step waits only while the device's queue is full."""
         cfg = self.cfg
         b, s = tokens.shape
         n_new = max_new_tokens or self.scfg.max_new_tokens
         with TraceAnnotation("engine.generate", batch=b, prompt_len=s,
                              new_tokens=n_new):
             with TraceAnnotation("engine.prefill"):
-                logits, caches, pos = prefill(self.params, cfg,
-                                              {"tokens": tokens})
+                logits, caches, pos = self._prefill(self.params, tokens)
             # grow KV caches to hold the generated tokens
             if not cfg.attn_free:
                 def grow(c):
@@ -87,24 +111,21 @@ class Engine:
                 seen.append(logits)
             for i in range(n_new - 1):
                 with StepTraceAnnotation("engine.decode_step", step_num=i):
-                    logits, caches = decode_step(self.params, cfg, tok,
-                                                 caches, pos + i)
-                    key = jax.random.fold_in(key, i)
+                    logits, caches, pos = self._decode(self.params, tok,
+                                                       caches, pos)
+                    if self.scfg.temperature > 0.0:   # greedy needs no key
+                        key = jax.random.fold_in(key, i)
                     tok = self._sample(logits, key)
                 out.append(tok)
                 if return_logits:
                     seen.append(logits)
             with TraceAnnotation("engine.fetch"):
-                ids = np.stack([np.asarray(t) for t in out], axis=1)
+                # device_get starts every copy before it waits on one
+                ids = np.stack(jax.device_get(out), axis=1)
                 if return_logits:
-                    return ids, np.stack([np.asarray(l) for l in seen],
-                                         axis=1)
+                    return ids, np.stack(jax.device_get(seen), axis=1)
             return ids
 
     def _sample(self, logits: jax.Array, key) -> jax.Array:
         with TraceAnnotation("engine.sample"):
-            if self.scfg.temperature <= 0.0:
-                return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            return jax.random.categorical(
-                key, logits / self.scfg.temperature,
-                axis=-1).astype(jnp.int32)
+            return self._pick(logits, key)

@@ -2,10 +2,11 @@
 
 The TPU compiler refuses what interpret mode accepts: block shapes that
 break the tiling, programs that do not fit the chip's memory. These tests
-compile the Pallas kernels at the widths of the configured models, and
-the full-width h2o-danube-1.8b decode step, for a chip of a described
-``v5e:2x2`` topology. Nothing runs, so they say nothing about results or
-times.
+compile the Pallas kernels at the widths of the configured models, the
+full-width h2o-danube-1.8b decode step, and the serving engine's own
+prefill and decode programs at the benchmark's chat batch, for a chip of
+a described ``v5e:2x2`` topology. Nothing runs, so they say nothing
+about results or times.
 
 The topology is described inside a module-scoped fixture, never at
 import time: only one process may load the TPU library, and test
@@ -25,6 +26,7 @@ from repro.kernels.decode_attention import flash_decode
 from repro.kernels.flash_attention import flash_attention_fwd
 from repro.kernels.rwkv6 import wkv6_chunked
 from repro.models import decode_step, init_decode_cache, init_params
+from repro.serve.engine import Engine
 
 V5E_HBM_BYTES = 16 * 2**30
 
@@ -91,21 +93,61 @@ def test_kernel_compiles_for_v5e(case, one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def _on_chip(tree, sharding):
+    return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=sharding), tree)
+
+
 def test_danube_decode_step_fits_one_v5e(one_chip):
     cfg = get_arch("h2o-danube-1.8b")
-
-    def on_chip(tree):
-        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
-            a.shape, a.dtype, sharding=one_chip), tree)
-
-    params = on_chip(jax.eval_shape(
-        partial(init_params, jax.random.PRNGKey(0), cfg)))
-    caches = on_chip(jax.eval_shape(
-        partial(init_decode_cache, cfg, 4, cfg.sliding_window)))
+    params = _on_chip(jax.eval_shape(
+        partial(init_params, jax.random.PRNGKey(0), cfg)), one_chip)
+    caches = _on_chip(jax.eval_shape(
+        partial(init_decode_cache, cfg, 4, cfg.sliding_window)), one_chip)
     ids = jax.ShapeDtypeStruct((4,), jnp.int32, sharding=one_chip)
     step = jax.jit(lambda p, tok, c, pos: decode_step(p, cfg, tok, c, pos))
     compiled = step.lower(params, ids, caches, ids).compile()
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes > 3.5e9   # the full bf16 weights
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        < V5E_HBM_BYTES
+
+
+# The chat batch: 32 prompts of 256 tokens, then 64 new tokens, so the
+# decode caches hold 320 positions.
+CHAT_BATCH, CHAT_PROMPT, CHAT_CACHE = 32, 256, 320
+SCOPES = {"prefill": "vmemkernel_flash_attention",
+          "decode": "vmemkernel_decode_attention"}
+
+
+@pytest.mark.parametrize("program", ["prefill", "decode"])
+def test_engine_program_fits_one_v5e(program, one_chip):
+    """The programs Engine.generate runs, compiled at full width: the
+    weights, the program's inputs, outputs and scratch fit one chip's
+    memory, and the decode step's new caches take the donated caches'
+    buffers."""
+    cfg = get_arch("h2o-danube-1.8b")
+    params = _on_chip(jax.eval_shape(
+        partial(init_params, jax.random.PRNGKey(0), cfg)), one_chip)
+    engine = Engine(cfg, params)
+    if program == "prefill":
+        tokens = jax.ShapeDtypeStruct((CHAT_BATCH, CHAT_PROMPT), I32,
+                                      sharding=one_chip)
+        compiled = engine._prefill.lower(params, tokens).compile()
+    else:
+        caches = _on_chip(jax.eval_shape(
+            partial(init_decode_cache, cfg, CHAT_BATCH, CHAT_CACHE)),
+            one_chip)
+        ids = jax.ShapeDtypeStruct((CHAT_BATCH,), I32, sharding=one_chip)
+        compiled = engine._decode.lower(params, ids, caches, ids).compile()
+        cache_bytes = sum(c.size * c.dtype.itemsize
+                          for c in jax.tree.leaves(caches))
+        assert compiled.memory_analysis().alias_size_in_bytes \
+            >= cache_bytes
+    # the benchmark's readers find each program by its attention's scope
+    assert SCOPES[program] in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes > 3.5e9   # the full bf16 weights
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes) \
         < V5E_HBM_BYTES

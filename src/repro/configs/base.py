@@ -25,11 +25,23 @@ class ArchConfig:
     sliding_window: Optional[int] = None      # SWA window (tokens)
     rope_theta: float = 1e4
 
+    # latent attention (MLA); kv_lora_rank 0 = GQA with n_kv_heads
+    kv_lora_rank: int = 0                     # width of the cached latent
+    qk_nope_head_dim: int = 0                 # per head: q/k part without RoPE
+    qk_rope_head_dim: int = 0                 # shared roped key, per head q
+    v_head_dim: int = 0
+
     # MoE
-    n_experts: int = 0
+    n_experts: int = 0                        # routed experts the router scores
     experts_per_token: int = 0
+    experts_held: int = 0                     # held here (0: all); experts 0..held-1
+    n_shared_experts: int = 0                 # one SwiGLU of n_shared * d_ff
+    router_scoring: str = "softmax"           # softmax | sigmoid (+ bias to select)
+    routed_scale: float = 1.0                 # on the normalised top-k weights
+    first_k_dense: int = 0                    # leading dense layers
+    dense_d_ff: int = 0                       # their SwiGLU width
     moe_dense_residual: bool = False          # arctic: parallel dense FFN
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25             # group-local dispatch only
 
     # SSM / hybrid
     attn_free: bool = False                   # rwkv6
@@ -60,20 +72,41 @@ class ArchConfig:
     def is_moe(self) -> bool:
         return self.n_experts > 0
 
+    @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def held(self) -> int:
+        """Routed experts whose weights this chip holds."""
+        return self.experts_held or self.n_experts
+
     def reduced(self) -> "ArchConfig":
-        """A tiny same-family config for CPU smoke tests."""
+        """A tiny same-family config for CPU smoke tests. Latent attention,
+        shared experts and leading dense layers are kept, and an expert
+        layer holds an eighth of 16 experts, as a chip of an 8-way
+        expert-parallel deployment does."""
+        mla = self.is_mla
+        n_experts = min(self.n_experts, 16)
         return dataclasses.replace(
             self,
             name=self.name + "-smoke",
-            n_layers=2,
+            n_layers=3 if self.first_k_dense else 2,
             d_model=64,
             n_heads=0 if self.attn_free else 4,
             n_kv_heads=0 if self.attn_free else max(1, min(self.n_kv_heads, 2)),
             head_dim=0 if self.attn_free else 16,
             d_ff=128,
             vocab_size=256,
-            n_experts=min(self.n_experts, 4),
-            experts_per_token=min(self.experts_per_token, 2),
+            n_experts=n_experts,
+            experts_per_token=min(self.experts_per_token, 4),
+            experts_held=n_experts // 8,
+            first_k_dense=min(self.first_k_dense, 1),
+            dense_d_ff=256 if self.dense_d_ff else 0,
+            kv_lora_rank=32 if mla else 0,
+            qk_nope_head_dim=16 if mla else 0,
+            qk_rope_head_dim=8 if mla else 0,
+            v_head_dim=16 if mla else 0,
             # drop-free capacity so prefill/decode agree exactly in tests
             capacity_factor=float(max(1, self.n_experts)),
             sliding_window=16 if self.sliding_window else None,
@@ -86,7 +119,13 @@ class ArchConfig:
         """Analytic parameter count (used for MODEL_FLOPS = 6·N·D)."""
         d, f, v = self.d_model, self.d_ff, self.vocab_size
         per_layer = 0
-        if not self.attn_free:
+        if self.is_mla:
+            h, r, rope = self.n_heads, self.kv_lora_rank, self.qk_rope_head_dim
+            per_layer += (d * h * (self.qk_nope_head_dim + rope)
+                          + d * (r + rope) + r
+                          + r * h * (self.qk_nope_head_dim + self.v_head_dim)
+                          + h * self.v_head_dim * d)
+        elif not self.attn_free:
             q = d * self.n_heads * self.hd
             kv = 2 * d * self.n_kv_heads * self.hd
             o = self.n_heads * self.hd * d
@@ -98,16 +137,21 @@ class ArchConfig:
         elif self.hybrid_ssm:
             di = self.n_heads * self.hd
             per_layer += 2 * d * di + di * (2 * self.ssm_state + 2) + di * d
-        if self.is_moe:
-            experts = self.n_experts * 3 * d * f
-            router = d * self.n_experts
-            per_layer += experts + router
-            if self.moe_dense_residual:
-                per_layer += 3 * d * f
-        elif not self.attn_free:
-            per_layer += 3 * d * f              # swiglu
         per_layer += 2 * d                      # norms
-        total = self.n_layers * per_layer + v * d + 2 * d
+        n_moe = self.n_layers - self.first_k_dense if self.is_moe else 0
+        ffn = (self.n_layers - n_moe) * 3 * d * (self.dense_d_ff or f)
+        if n_moe:
+            experts = self.held * 3 * d * f
+            router = d * self.n_experts
+            if self.router_scoring == "sigmoid":
+                router += self.n_experts          # the selection bias
+            ffn += n_moe * (experts + router
+                            + self.n_shared_experts * 3 * d * f)
+            if self.moe_dense_residual:
+                ffn += n_moe * 3 * d * f
+        elif self.attn_free:
+            ffn = 0                              # counted with the block
+        total = self.n_layers * per_layer + ffn + v * d + 2 * d
         if not self.tie_embeddings:
             total += v * d
         return total
@@ -117,8 +161,8 @@ class ArchConfig:
         if not self.is_moe:
             return self.param_count()
         d, f = self.d_model, self.d_ff
-        inactive = self.n_layers * (self.n_experts - self.experts_per_token) \
-            * 3 * d * f
+        inactive = (self.n_layers - self.first_k_dense) \
+            * (self.held - self.experts_per_token) * 3 * d * f
         return self.param_count() - inactive
 
 

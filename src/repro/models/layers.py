@@ -76,7 +76,8 @@ def causal_attention_ref(q: jax.Array, k: jax.Array, v: jax.Array,
                          window: Optional[int] = None,
                          q_offset: int = 0,
                          chunk: int = 512) -> jax.Array:
-    """Chunked causal attention. q: (B,Sq,H,hd), k/v: (B,Sk,H,hd).
+    """Chunked causal attention. q, k: (B,Sq,H,hd), (B,Sk,H,hd); v:
+    (B,Sk,H,hd_v), which may be narrower (latent attention).
 
     ``q_offset``: absolute position of q[0] relative to k[0] (decode:
     Sk-1). Memory is O(Sq_chunk * Sk), never O(Sq*Sk) at once. Each chunk
@@ -84,7 +85,7 @@ def causal_attention_ref(q: jax.Array, k: jax.Array, v: jax.Array,
     are never stashed across chunks).
     """
     b, sq, h, hd = q.shape
-    sk = k.shape[1]
+    sk, hd_v = k.shape[1], v.shape[-1]
     scale = 1.0 / math.sqrt(hd)
     kpos = jnp.arange(sk)
 
@@ -119,7 +120,7 @@ def causal_attention_ref(q: jax.Array, k: jax.Array, v: jax.Array,
     qp = qp.reshape(b, n_chunks, chunk, h, hd).transpose(1, 0, 2, 3, 4)
     pos = (q_offset + jnp.arange(n_chunks * chunk)).reshape(n_chunks, chunk)
     out = jax.lax.map(lambda args: attend(*args), (qp, pos))
-    out = out.transpose(1, 0, 2, 3, 4).reshape(b, n_chunks * chunk, h, hd)
+    out = out.transpose(1, 0, 2, 3, 4).reshape(b, n_chunks * chunk, h, hd_v)
     return out[:, :sq]
 
 
@@ -161,6 +162,8 @@ def swiglu(x: jax.Array, w_gate: jax.Array, w_up: jax.Array,
 
 # ------------------------------------------------------------- init
 def dense_init(key: jax.Array, shape: tuple, dtype, scale: float = 1.0):
-    fan_in = shape[0] if len(shape) > 1 else 1
+    """N(0, scale^2 / fan_in); a stack of matrices (rank 3) is read as
+    (stack, fan_in, fan_out)."""
+    fan_in = shape[-2] if len(shape) > 1 else 1
     std = scale / math.sqrt(fan_in)
     return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)

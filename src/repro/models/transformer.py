@@ -1,14 +1,21 @@
 """Unified decoder stack covering all 10 architectures.
 
-One scan-over-layers decoder parameterized by ArchConfig:
-* dense / MoE SwiGLU MLPs (+ arctic's parallel dense residual)
-* GQA attention with RoPE, optional qk_norm / QKV bias / sliding window
+A scan-over-layers decoder parameterized by ArchConfig:
+* dense / MoE SwiGLU MLPs (+ arctic's parallel dense residual, shared
+  experts, a held share of the routed experts)
+* GQA attention with RoPE, optional qk_norm / QKV bias / sliding window,
+  or latent attention (MLA) with a latent decode cache
 * RWKV6 blocks (attention-free)
 * hymba hybrid blocks (parallel attention + mamba heads)
 * VLM/audio variants take precomputed frontend embeddings (stub)
 
-Layers are stacked (leading axis = layer) and applied with ``lax.scan`` —
-compile time is O(1) in depth; remat is applied per layer for training.
+Like layers are stacked (leading axis = layer) and applied with
+``lax.scan`` — compile time is O(1) in depth; remat is applied per layer
+for training. A stack with leading dense layers before its expert layers
+(``first_k_dense``) scans each run of like layers in turn: params and
+caches hold one stacked tree per run, keyed as ``segments`` names them.
+An expert layer's decode cache also counts, under ``routed``, the tokens
+its held experts took since prefill (``expert_counts`` sums them).
 
 Three entry points:
   forward_train   tokens/embeds -> chunked-CE loss (never materializes
@@ -19,8 +26,7 @@ Three entry points:
 
 from __future__ import annotations
 
-import math
-from functools import partial
+import dataclasses
 from typing import Any, Optional
 
 import jax
@@ -28,7 +34,7 @@ import jax.numpy as jnp
 
 from ..configs.base import ArchConfig
 from ..sharding.ctx import constrain
-from . import ssm
+from . import mla, ssm
 from .layers import (apply_rope, causal_attention_ref, decode_attention_ref,
                      dense_init, repeat_kv, rms_norm, rope_tables)
 from .moe import apply_moe, init_moe
@@ -38,6 +44,17 @@ LOSS_CHUNK = 1024
 
 def _dtype(cfg: ArchConfig):
     return jnp.dtype(cfg.param_dtype)
+
+
+def segments(cfg: ArchConfig) -> list[tuple[str, ArchConfig]]:
+    """Each run of like layers, in order: its key in params and caches,
+    and the config its layers follow (``n_layers`` = the run's length)."""
+    if not (cfg.is_moe and cfg.first_k_dense):
+        return [("layers", cfg)]
+    lead = dataclasses.replace(cfg, n_layers=cfg.first_k_dense, n_experts=0,
+                               d_ff=cfg.dense_d_ff or cfg.d_ff)
+    rest = dataclasses.replace(cfg, n_layers=cfg.n_layers - cfg.first_k_dense)
+    return [("dense_layers", lead), ("layers", rest)]
 
 
 # ================================================================= init
@@ -72,7 +89,7 @@ def init_layer(key: jax.Array, cfg: ArchConfig) -> dict:
         p["tmix"] = ssm.init_rwkv_tmix(ks[0], cfg, dtype)
         p["cmix"] = ssm.init_rwkv_cmix(ks[1], cfg, dtype)
         return p
-    p["attn"] = init_attn(ks[0], cfg, dtype)
+    p["attn"] = (mla.init_mla if cfg.is_mla else init_attn)(ks[0], cfg, dtype)
     if cfg.hybrid_ssm:
         p["mamba"] = ssm.init_mamba(ks[1], cfg, dtype)
     if cfg.is_moe:
@@ -92,9 +109,13 @@ def init_params(key: jax.Array, cfg: ArchConfig) -> dict:
     layer_keys = jax.random.split(k_layers, cfg.n_layers)
     params = {
         "embed": dense_init(k_embed, (cfg.vocab_size, cfg.d_model), dtype),
-        "layers": jax.vmap(lambda k: init_layer(k, cfg))(layer_keys),
         "final_norm": jnp.ones((cfg.d_model,), jnp.float32),
     }
+    first = 0
+    for name, seg in segments(cfg):
+        keys = layer_keys[first:first + seg.n_layers]
+        params[name] = jax.vmap(lambda k: init_layer(k, seg))(keys)
+        first += seg.n_layers
     if not cfg.tie_embeddings:
         params["lm_head"] = dense_init(
             k_head, (cfg.d_model, cfg.vocab_size), dtype)
@@ -172,6 +193,10 @@ def apply_attn_decode(p: dict, x: jax.Array, cfg: ArchConfig,
 
 
 # =============================================================== blocks
+def _attn_cache(cfg: ArchConfig, cache: dict) -> dict:
+    return cache if cfg.is_mla else {"kv": cache}
+
+
 def apply_block_seq(lp: dict, x: jax.Array, cfg: ArchConfig,
                     rope: tuple):
     """One layer over a full sequence. Returns (x, aux_loss, cache)."""
@@ -185,18 +210,20 @@ def apply_block_seq(lp: dict, x: jax.Array, cfg: ArchConfig,
         return x, aux, cache
     x = constrain(x, "dp", "sp", None)   # seq-parallel residual stream
     normed = rms_norm(x, lp["ln1"], cfg.norm_eps)
-    attn_out, kv = apply_attn_seq(lp["attn"], normed, cfg, rope)
+    attn = mla.apply_mla_seq if cfg.is_mla else apply_attn_seq
+    attn_out, kv = attn(lp["attn"], normed, cfg, rope)
+    cache = _attn_cache(cfg, kv)
     if cfg.hybrid_ssm:
         ssm_out, mstate = ssm.apply_mamba(lp["mamba"], normed, cfg)
         x = x + 0.5 * (attn_out + ssm_out)
-        cache = {"kv": kv, "mamba": mstate}
+        cache["mamba"] = mstate
     else:
         x = x + constrain(attn_out, "dp", "sp", None)
-        cache = {"kv": kv}
     normed2 = rms_norm(x, lp["ln2"], cfg.norm_eps)
     if cfg.is_moe:
         b, s, d = normed2.shape
-        out, aux = apply_moe(lp["moe"], normed2.reshape(b * s, d), cfg)
+        out, aux, cache["routed"] = apply_moe(
+            lp["moe"], normed2.reshape(b * s, d), cfg)
         x = x + constrain(out.reshape(b, s, d), "dp", "sp", None)
     else:
         m = lp["mlp"]
@@ -221,19 +248,25 @@ def apply_block_decode(lp: dict, x: jax.Array, cfg: ArchConfig,
         x = x + h
         return x, {"tmix": tstate, "cmix": cstate}
     normed = rms_norm(x, lp["ln1"], cfg.norm_eps)
-    attn_out, kv = apply_attn_decode(lp["attn"], normed, cfg, cache["kv"], pos)
+    if cfg.is_mla:
+        attn_out, kv = mla.apply_mla_decode(lp["attn"], normed, cfg, cache,
+                                            pos)
+    else:
+        attn_out, kv = apply_attn_decode(lp["attn"], normed, cfg,
+                                         cache["kv"], pos)
+    new_cache = _attn_cache(cfg, kv)
     if cfg.hybrid_ssm:
         ssm_out, mstate = ssm.apply_mamba(lp["mamba"], normed, cfg,
                                           state=cache["mamba"])
         x = x + 0.5 * (attn_out + ssm_out)
-        new_cache = {"kv": kv, "mamba": mstate}
+        new_cache["mamba"] = mstate
     else:
         x = x + attn_out
-        new_cache = {"kv": kv}
     normed2 = rms_norm(x, lp["ln2"], cfg.norm_eps)
     if cfg.is_moe:
         b, s, d = normed2.shape
-        out, _ = apply_moe(lp["moe"], normed2.reshape(b * s, d), cfg)
+        out, _, counts = apply_moe(lp["moe"], normed2.reshape(b * s, d), cfg)
+        new_cache["routed"] = jax.tree.map(jnp.add, cache["routed"], counts)
         x = x + out.reshape(b, s, d)
     else:
         m = lp["mlp"]
@@ -254,35 +287,41 @@ def _embed_inputs(params: dict, cfg: ArchConfig, batch: dict) -> jax.Array:
 def _stack_layers(params: dict, cfg: ArchConfig, x: jax.Array,
                   rope: tuple, with_cache: bool,
                   remat: bool, unroll: bool = False):
-    def body(carry, lp):
-        x, aux = carry
-        x, a, cache = apply_block_seq(lp, x, cfg, rope)
-        out = cache if with_cache else None
-        return (x, aux + a), out
+    """Every layer over full sequences. Returns (x, aux_loss, caches per
+    segment or None)."""
+    aux = jnp.zeros((), jnp.float32)
+    caches = {}
+    for name, seg in segments(cfg):
+        def body(carry, lp, seg=seg):
+            x, aux = carry
+            x, a, cache = apply_block_seq(lp, x, seg, rope)
+            out = cache if with_cache else None
+            return (x, aux + a), out
 
-    if remat:
-        body = jax.checkpoint(body, prevent_cse=False)
-    carry0 = (x, jnp.zeros((), jnp.float32))
-    if unroll:
-        # python-loop unroll (debug/validation: XLA cost_analysis counts
-        # every op; no while-loop trip ambiguity)
-        caches = []
-        carry = carry0
-        for i in range(cfg.n_layers):
-            lp = jax.tree.map(lambda a: a[i], params["layers"])
-            carry, out = body(carry, lp)
-            caches.append(out)
-        x, aux = carry
-        caches = None if not with_cache else jax.tree.map(
-            lambda *xs: jnp.stack(xs), *caches)
-        return x, aux, caches
-    (x, aux), caches = jax.lax.scan(body, carry0, params["layers"])
-    return x, aux, caches
+        if remat:
+            body = jax.checkpoint(body, prevent_cse=False)
+        if unroll:
+            # python-loop unroll (debug/validation: XLA cost_analysis
+            # counts every op; no while-loop trip ambiguity)
+            outs = []
+            carry = (x, aux)
+            for i in range(seg.n_layers):
+                lp = jax.tree.map(lambda a: a[i], params[name])
+                carry, out = body(carry, lp)
+                outs.append(out)
+            (x, aux) = carry
+            out = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
+        else:
+            (x, aux), out = jax.lax.scan(body, (x, aux), params[name])
+        caches[name] = out
+    return x, aux, caches if with_cache else None
 
 
 def _rope_for(cfg: ArchConfig, s: int) -> tuple:
     if cfg.attn_free:
         return ()
+    if cfg.is_mla:
+        return mla.mla_rope(cfg, jnp.arange(s))
     # 1-D positions: broadcast over batch AND heads without materializing
     return rope_tables(jnp.arange(s), cfg.hd, cfg.rope_theta)
 
@@ -338,14 +377,43 @@ def prefill(params: dict, cfg: ArchConfig, batch: dict):
                                  with_cache=True, remat=False)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = (x[:, -1, :] @ lm_head_weight(params, cfg)).astype(jnp.float32)
-    if not cfg.attn_free and caches is not None:
-        # prefill caches: reorder kv to (L, B, S, Hkv, hd) is already so
-        pass
     return logits, caches, jnp.full((b,), s, jnp.int32)
 
 
+def expert_counts(caches: dict) -> dict:
+    """The counts of apply_moe (``expert_tokens`` per held expert,
+    ``expert_loads``) that the expert layers' caches hold, summed over the
+    layers: since prefill, prefill's included. Empty without experts."""
+    routed = [c["routed"] for c in caches.values() if "routed" in c]
+    return jax.tree.map(lambda *a: sum(jnp.sum(x, axis=0) for x in a),
+                        *routed) if routed else {}
+
+
+# Sequence caches, (L, B, S, ...): the engine grows these to hold the
+# tokens it generates. Recurrent states (rwkv, mamba) keep their size.
+SEQUENCE_CACHES = frozenset({"k", "v", "latent"})
+
+
+def grow_decode_cache(caches: dict, n: int) -> dict:
+    """``caches`` with room for ``n`` more positions in each sequence
+    cache."""
+    def grow(path, c):
+        if getattr(path[-1], "key", None) not in SEQUENCE_CACHES:
+            return c
+        pad = [(0, 0)] * c.ndim
+        pad[2] = (0, n)
+        return jnp.pad(c, pad)
+    return jax.tree_util.tree_map_with_path(grow, caches)
+
+
 def init_decode_cache(cfg: ArchConfig, batch_size: int, max_len: int) -> dict:
-    """Blank decode caches (used to lower serve_step without a prefill)."""
+    """Blank decode caches (used to lower serve_step without a prefill),
+    one stacked tree per segment."""
+    return {name: _blank_cache(seg, batch_size, max_len)
+            for name, seg in segments(cfg)}
+
+
+def _blank_cache(cfg: ArchConfig, batch_size: int, max_len: int) -> dict:
     dtype = _dtype(cfg)
     L = cfg.n_layers
 
@@ -360,10 +428,19 @@ def init_decode_cache(cfg: ArchConfig, batch_size: int, max_len: int) -> dict:
             }
         size = max_len if cfg.sliding_window is None \
             else min(max_len, cfg.sliding_window)
-        c = {"kv": {
-            "k": jnp.zeros((batch_size, size, cfg.n_kv_heads, cfg.hd), dtype),
-            "v": jnp.zeros((batch_size, size, cfg.n_kv_heads, cfg.hd), dtype),
-        }}
+        if cfg.is_mla:
+            c = {"latent": jnp.zeros(
+                (batch_size, size, mla.latent_width(cfg)), dtype)}
+        else:
+            c = {"kv": {
+                "k": jnp.zeros((batch_size, size, cfg.n_kv_heads, cfg.hd),
+                               dtype),
+                "v": jnp.zeros((batch_size, size, cfg.n_kv_heads, cfg.hd),
+                               dtype),
+            }}
+        if cfg.is_moe:
+            c["routed"] = {"expert_tokens": jnp.zeros((cfg.held,), jnp.int32),
+                           "expert_loads": jnp.zeros((), jnp.int32)}
         if cfg.hybrid_ssm:
             di = cfg.n_heads * cfg.hd
             c["mamba"] = {
@@ -386,12 +463,14 @@ def decode_step(params: dict, cfg: ArchConfig, tokens: jax.Array,
     else:
         x = params["embed"][tokens][:, None, :]
 
-    def body(x, lp_cache):
-        lp, cache = lp_cache
-        x, new_cache = apply_block_decode(lp, x, cfg, cache, pos)
-        return x, new_cache
+    new_caches = {}
+    for name, seg in segments(cfg):
+        def body(x, lp_cache, seg=seg):
+            lp, cache = lp_cache
+            return apply_block_decode(lp, x, seg, cache, pos)
 
-    x, new_caches = jax.lax.scan(body, x, (params["layers"], caches))
+        x, new_caches[name] = jax.lax.scan(body, x,
+                                           (params[name], caches[name]))
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = (x[:, 0, :] @ lm_head_weight(params, cfg)).astype(jnp.float32)
     return logits, new_caches

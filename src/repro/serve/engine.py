@@ -14,7 +14,7 @@ import numpy as np
 from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from ..configs.base import ArchConfig
-from ..models import decode_step, prefill
+from ..models import decode_step, expert_counts, grow_decode_cache, prefill
 
 
 @dataclass
@@ -45,11 +45,16 @@ class Engine:
         if registry is not None:
             # leased read: which checkpoint should we be serving?
             self.model_version = registry.latest_checkpoint()
+        # The expert counts of every batch served, per phase (apply_moe's,
+        # summed over layers; empty without experts), fetched with each
+        # batch's ids.
+        self._counts: dict = {}
         # One compiled program per phase and shape, built once. The decode
-        # step also advances the positions, and its new caches take the
-        # buffers of the caches it is given. Every program in flight holds
-        # one of the device queue's slots, so a step issues two (decode
-        # and sample) and the host runs that much further ahead.
+        # step also advances the positions (and, in the caches, the expert
+        # counts), and its new caches take the buffers of the caches it is
+        # given. Every program in flight holds one of the device queue's
+        # slots, so a step issues two (decode and sample) and the host
+        # runs that much further ahead.
         temperature = serve_cfg.temperature
 
         def serve_prefill(params, tokens):
@@ -93,16 +98,12 @@ class Engine:
                              new_tokens=n_new):
             with TraceAnnotation("engine.prefill"):
                 logits, caches, pos = self._prefill(self.params, tokens)
-            # grow KV caches to hold the generated tokens
+                # a copy: the decode steps take over the caches' buffers
+                prefilled = expert_counts(caches)
+            # grow the sequence caches to hold the generated tokens
             if not cfg.attn_free:
-                def grow(c):
-                    if c.ndim == 5:   # (L, B, S, Hkv, hd)
-                        pad = [(0, 0)] * 5
-                        pad[2] = (0, n_new)
-                        return jnp.pad(c, pad)
-                    return c
                 with TraceAnnotation("engine.grow_cache"):
-                    caches = jax.tree.map(grow, caches)
+                    caches = grow_decode_cache(caches, n_new)
             out, seen = [], []
             key = jax.random.PRNGKey(self.scfg.seed)
             tok = self._sample(logits, key)
@@ -121,10 +122,30 @@ class Engine:
                     seen.append(logits)
             with TraceAnnotation("engine.fetch"):
                 # device_get starts every copy before it waits on one
-                ids = np.stack(jax.device_get(out), axis=1)
+                out, prefilled, counts, seen = jax.device_get(
+                    (out, prefilled, expert_counts(caches), seen))
+                ids = np.stack(out, axis=1)
+                self._count(prefilled, counts)
                 if return_logits:
-                    return ids, np.stack(jax.device_get(seen), axis=1)
+                    return ids, np.stack(seen, axis=1)
             return ids
+
+    def _count(self, prefilled: dict, total: dict) -> None:
+        if not total:
+            return
+        batch = {"prefill": prefilled,
+                 "decode": jax.tree.map(np.subtract, total, prefilled)}
+        batch = jax.tree.map(lambda c: np.asarray(c, np.int64), batch)
+        self._counts = jax.tree.map(np.add, self._counts, batch) \
+            if self._counts else batch
+
+    def stats(self) -> dict:
+        """Counts over every batch served so far, for ``prefill`` and for
+        ``decode``, as host arrays: ``expert_tokens``, the tokens routed to
+        each held expert, summed over layers, and ``expert_loads``, the
+        (layer call, held expert) pairs that had any token. Empty for a
+        model without experts."""
+        return jax.tree.map(np.copy, self._counts)
 
     def _sample(self, logits: jax.Array, key) -> jax.Array:
         with TraceAnnotation("engine.sample"):

@@ -35,6 +35,10 @@ _RULES: dict[str, tuple] = {
     "wq": ("fsdp", "tp"), "wk": ("fsdp", "tp"), "wv": ("fsdp", "tp"),
     "wo": ("tp", "fsdp"),
     "bq": ("tp",), "bk": ("tp",), "bv": ("tp",),
+    # latent attention: the latent is shared by every head, so its
+    # down-projection keeps its output whole; the up-projection splits
+    # heads
+    "wkv_a": ("fsdp", None), "wkv_b": (None, "tp"),
     # dense mlp
     "w_gate": ("fsdp", "tp"), "w_up": ("fsdp", "tp"), "w_down": ("tp", "fsdp"),
     # rwkv time/channel mix
@@ -49,6 +53,8 @@ _RULES: dict[str, tuple] = {
     # moe (expert-parallel)
     "router": ("fsdp", None),
 }
+# keys of stacked layer runs (leading axis = layer)
+_STACKS = ("layers", "dense_layers")
 # MoE expert tensors are rank-3 and share names with dense mlp weights;
 # disambiguated by rank below.
 _MOE_RULES = {
@@ -112,7 +118,7 @@ def param_specs(params_tree: Any, mesh: Mesh, mode: str = "train") -> Any:
 
     def one(path, leaf):
         names = _path_names(path)
-        stacked = "layers" in names
+        stacked = any(n in _STACKS for n in names)
         spec = _spec_for(names, leaf.shape, mesh_axes, stacked)
         if mode == "serve":
             spec = P(*[None if a in ("data", ("pod", "data"), "pod") else a
@@ -131,7 +137,7 @@ def opt_specs(opt_tree: Any, params_spec_tree: Any, mesh: Mesh) -> Any:
         # trailing factored key ("row"/"col"/"v")
         inner = [n for n in names if n not in ("m", "v", "ef", "f", "row", "col")]
         mesh_axes = dict(zip(mesh.axis_names, mesh.devices.shape))
-        stacked = "layers" in inner
+        stacked = any(n in _STACKS for n in inner)
         tail = names[-1]
         base = _spec_for(inner, leaf.shape, mesh_axes, stacked)
         if tail == "row" or tail == "col":
@@ -170,7 +176,9 @@ def cache_specs(cache_tree: Any, mesh: Mesh) -> Any:
     (L, B, S, Hkv, hd) prefer the kv-head dim, falling back to the head
     dim (all zoo archs have hd % 16 == 0). A 32k-deep MHA cache
     (musicgen: 3.3 TB global) does not fit per-device memory under
-    batch-only sharding."""
+    batch-only sharding. The latent cache (L, B, S, r + rope) is read
+    whole by every head, so only its batch is sharded; the expert layers'
+    counts are replicated."""
     mesh_axes = dict(zip(mesh.axis_names, mesh.axis_sizes))
     dp = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
     dp_axes = dp if len(dp) > 1 else (dp[0] if dp else None)
@@ -179,10 +187,15 @@ def cache_specs(cache_tree: Any, mesh: Mesh) -> Any:
         dp_size *= mesh_axes[a]
     tp = mesh_axes.get("model", 1)
 
-    def one(leaf):
+    def one(path, leaf):
         spec = [None] * leaf.ndim
+        names = _path_names(path)
+        if "routed" in names:        # expert counts (L, held): replicated
+            return P(*spec)
         if leaf.ndim >= 2 and leaf.shape[1] % dp_size == 0:
             spec[1] = dp_axes
+        if names[-1:] == ["latent"]:
+            return P(*spec)
         if leaf.ndim >= 4:
             # try feature dims from the head dim outward: Hkv then hd
             if leaf.ndim >= 5 and leaf.shape[3] % tp == 0:
@@ -191,7 +204,7 @@ def cache_specs(cache_tree: Any, mesh: Mesh) -> Any:
                 spec[-1] = "model"
         return P(*spec)
 
-    return jax.tree.map(one, cache_tree)
+    return jax.tree_util.tree_map_with_path(one, cache_tree)
 
 
 def state_specs(state_shapes: dict, mesh: Mesh) -> dict:

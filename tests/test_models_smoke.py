@@ -7,8 +7,8 @@ import jax.numpy as jnp
 import pytest
 
 from repro.configs import ARCHS
-from repro.models import (decode_step, forward_train, init_decode_cache,
-                          init_params, prefill)
+from repro.models import (decode_step, forward_train, grow_decode_cache,
+                          init_decode_cache, init_params, prefill)
 
 ARCH_IDS = sorted(ARCHS)
 
@@ -73,14 +73,8 @@ def test_prefill_then_decode_matches_full_forward(arch_id):
     # prefill s-1 tokens, then decode token s-1
     logits_pre, caches, pos = prefill(params, cfg,
                                       {"tokens": tokens[:, :-1]})
-    if not cfg.attn_free:
-        # grow the kv cache to hold the decode token
-        def grow(c):
-            pad = [(0, 0)] * c.ndim
-            pad[2] = (0, 4)  # (L, B, S, H, hd): pad S
-            return jnp.pad(c, pad)
-        caches = jax.tree.map(
-            lambda c: grow(c) if c.ndim == 5 else c, caches)
+    # grow the sequence caches (KV or latent) to hold the decode token
+    caches = grow_decode_cache(caches, 4)
     logits_dec, _ = decode_step(params, cfg, tokens[:, -1], caches, pos)
     assert jnp.allclose(logits_dec, logits_full, atol=2e-2, rtol=2e-2), \
         f"{arch_id}: max diff {jnp.abs(logits_dec - logits_full).max()}"

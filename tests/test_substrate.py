@@ -136,8 +136,11 @@ def test_roofline_counts_collectives():
 
 
 def test_model_flops_moe_counts_active_only():
-    moe = get_arch("moonshot-v1-16b-a3b")
+    moe = get_arch("moonlight-16b-a3b")
     assert moe.active_param_count() < 0.35 * moe.param_count()
+    # the published 2.9 B active: embedding, head, MLA, the dense layer,
+    # shared and 6 routed experts in each of 26 layers
+    assert 2.8e9 < moe.active_param_count() < 3.0e9
     dense = get_arch("qwen3-8b")
     assert dense.active_param_count() == dense.param_count()
     # sanity: param counts in the right ballpark
@@ -145,27 +148,43 @@ def test_model_flops_moe_counts_active_only():
     assert 300e9 < get_arch("arctic-480b").param_count() < 600e9
 
 
+@pytest.mark.parametrize("held, low, high", [
+    (0, 15.5e9, 16.5e9),     # the whole model: 64 routed experts a layer
+    (8, 3.3e9, 3.4e9),       # one chip's share of 8-way expert parallelism
+])
+def test_moonlight_parameter_count(held, low, high):
+    import dataclasses
+    cfg = dataclasses.replace(get_arch("moonlight-16b-a3b"),
+                              experts_held=held)
+    assert low < cfg.param_count() < high
+
+
 # ------------------------------------------------------------------- moe
 def test_grouped_moe_matches_flat_dispatch():
     """The grouped dispatch (§Perf iteration 6, off by default) must be
-    numerically equivalent to flat dispatch when capacity is ample."""
+    numerically equivalent to the sorted dispatch when capacity is ample,
+    under sigmoid routing with a held share and shared experts."""
     import dataclasses
     from repro.models.moe import apply_moe, init_moe
     from repro.sharding import ctx
 
-    cfg = get_arch("moonshot-v1-16b-a3b").reduced()
+    cfg = get_arch("moonlight-16b-a3b").reduced()
+    assert cfg.router_scoring == "sigmoid" and cfg.held < cfg.n_experts
     cfg = dataclasses.replace(cfg, capacity_factor=float(cfg.n_experts))
     key = jax.random.PRNGKey(0)
     p = init_moe(key, cfg, jnp.float32)
     x = jax.random.normal(jax.random.fold_in(key, 1), (32, cfg.d_model),
                           jnp.float32) * 0.1
     ctx.set_moe_groups(1)
-    flat, aux1 = apply_moe(p, x, cfg)
+    flat, aux1, counts1 = apply_moe(p, x, cfg)
     ctx.set_moe_groups(4)
     try:
-        grouped, aux2 = apply_moe(p, x, cfg)
+        grouped, aux2, counts2 = apply_moe(p, x, cfg)
     finally:
         ctx.set_moe_groups(1)
+    assert int(counts1["expert_tokens"].sum()) > 0
+    np.testing.assert_array_equal(counts1["expert_tokens"],
+                                  counts2["expert_tokens"])
     np.testing.assert_allclose(np.asarray(flat), np.asarray(grouped),
                                atol=1e-5, rtol=1e-4)
     np.testing.assert_allclose(float(aux1), float(aux2), rtol=1e-5)

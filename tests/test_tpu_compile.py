@@ -4,15 +4,16 @@ The TPU compiler refuses what interpret mode accepts: block shapes that
 break the tiling, programs that do not fit the chip's memory. These tests
 compile the Pallas kernels at the widths of the configured models, the
 full-width h2o-danube-1.8b decode step, and the serving engine's own
-prefill and decode programs at the benchmark's chat batch, for a chip of
-a described ``v5e:2x2`` topology. Nothing runs, so they say nothing
-about results or times.
+prefill and decode programs at the shapes of the benchmark's serving
+cells, for a chip of a described ``v5e:2x2`` topology. Nothing runs, so
+they say nothing about results or times.
 
 The topology is described inside a module-scoped fixture, never at
 import time: only one process may load the TPU library, and test
 collection must not depend on whether it did.
 """
 
+import dataclasses
 import os
 from functools import partial
 
@@ -113,32 +114,40 @@ def test_danube_decode_step_fits_one_v5e(one_chip):
         < V5E_HBM_BYTES
 
 
-# The chat batch: 32 prompts of 256 tokens, then 64 new tokens, so the
-# decode caches hold 320 positions.
-CHAT_BATCH, CHAT_PROMPT, CHAT_CACHE = 32, 256, 320
+# The served shapes of the benchmark's cells: (config, batch, prompt
+# length, decode cache length = prompt + new tokens, bytes the weights
+# exceed). danube chat: 32 prompts of 256 tokens, 64 new; danube docqa:
+# 4 of 4080, 16 new, filling the 4096 window; Moonlight, one chip's
+# 8-expert share: 64 of 1024, 128 new (3.36 B parameters, 6.73 GB).
+SERVED = {
+    "danube-chat": ("h2o-danube-1.8b", 0, 32, 256, 320, 3.5e9),
+    "danube-docqa": ("h2o-danube-1.8b", 0, 4, 4080, 4096, 3.5e9),
+    "moonlight-ep8": ("moonlight-16b-a3b", 8, 64, 1024, 1152, 6.5e9),
+}
 SCOPES = {"prefill": "vmemkernel_flash_attention",
           "decode": "vmemkernel_decode_attention"}
 
 
 @pytest.mark.parametrize("program", ["prefill", "decode"])
-def test_engine_program_fits_one_v5e(program, one_chip):
+@pytest.mark.parametrize("served", sorted(SERVED))
+def test_engine_program_fits_one_v5e(served, program, one_chip):
     """The programs Engine.generate runs, compiled at full width: the
     weights, the program's inputs, outputs and scratch fit one chip's
     memory, and the decode step's new caches take the donated caches'
     buffers."""
-    cfg = get_arch("h2o-danube-1.8b")
+    arch, held, batch, prompt, cache_len, weight_bytes = SERVED[served]
+    cfg = dataclasses.replace(get_arch(arch), experts_held=held)
     params = _on_chip(jax.eval_shape(
         partial(init_params, jax.random.PRNGKey(0), cfg)), one_chip)
     engine = Engine(cfg, params)
     if program == "prefill":
-        tokens = jax.ShapeDtypeStruct((CHAT_BATCH, CHAT_PROMPT), I32,
+        tokens = jax.ShapeDtypeStruct((batch, prompt), I32,
                                       sharding=one_chip)
         compiled = engine._prefill.lower(params, tokens).compile()
     else:
         caches = _on_chip(jax.eval_shape(
-            partial(init_decode_cache, cfg, CHAT_BATCH, CHAT_CACHE)),
-            one_chip)
-        ids = jax.ShapeDtypeStruct((CHAT_BATCH,), I32, sharding=one_chip)
+            partial(init_decode_cache, cfg, batch, cache_len)), one_chip)
+        ids = jax.ShapeDtypeStruct((batch,), I32, sharding=one_chip)
         compiled = engine._decode.lower(params, ids, caches, ids).compile()
         cache_bytes = sum(c.size * c.dtype.itemsize
                           for c in jax.tree.leaves(caches))
@@ -147,7 +156,7 @@ def test_engine_program_fits_one_v5e(program, one_chip):
     # the benchmark's readers find each program by its attention's scope
     assert SCOPES[program] in compiled.as_text()
     mem = compiled.memory_analysis()
-    assert mem.argument_size_in_bytes > 3.5e9   # the full bf16 weights
+    assert mem.argument_size_in_bytes > weight_bytes   # full bf16 weights
     assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
             - mem.alias_size_in_bytes + mem.temp_size_in_bytes) \
         < V5E_HBM_BYTES

@@ -152,6 +152,21 @@ def decode_attention_ref(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
         return out.reshape(b, 1, h, hd).astype(q.dtype)
 
 
+def write_rows(cache: jax.Array, rows: jax.Array, slot: jax.Array,
+               layer: Optional[jax.Array] = None):
+    """A sequence cache with one new row per sequence, ``rows[b]`` at
+    slot ``slot[b]``: in the stack of every layer's cache (L, B, C, ...)
+    at ``layer``, or in one layer's (B, C, ...) when ``layer`` is None.
+    Only the rows are written, so a stack carried through the layer scan
+    is updated in place. Returns the cache and this layer's part of it."""
+    at = (jnp.arange(rows.shape[0]), slot)
+    if layer is None:
+        cache = cache.at[at].set(rows)
+        return cache, cache
+    cache = cache.at[(layer, *at)].set(rows)
+    return cache, cache[layer]
+
+
 # ------------------------------------------------------------- MLP
 def swiglu(x: jax.Array, w_gate: jax.Array, w_up: jax.Array,
            w_down: jax.Array) -> jax.Array:

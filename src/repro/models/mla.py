@@ -24,13 +24,14 @@ random weights that only permutes columns of ``W_q`` and ``W_kva``).
 from __future__ import annotations
 
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
 from ..configs.base import ArchConfig
 from .layers import apply_rope, causal_attention_ref, dense_init, rms_norm, \
-    rope_tables
+    rope_tables, write_rows
 
 # Bytes of prefill scores one query chunk may hold (f32), so that the
 # scores of a large batch never exist at once.
@@ -91,16 +92,18 @@ def apply_mla_seq(p: dict, x: jax.Array, cfg: ArchConfig,
 
 
 def apply_mla_decode(p: dict, x: jax.Array, cfg: ArchConfig, cache: dict,
-                     pos: jax.Array) -> tuple[jax.Array, dict]:
+                     pos: jax.Array, layer: Optional[jax.Array] = None
+                     ) -> tuple[jax.Array, dict]:
     """One token per sequence against the latent cache (B, C, r + rope),
-    ``W_kvb`` absorbed into the query and the output. pos: (B,)."""
+    or against every layer's stacked (L, B, C, r + rope) at ``layer``
+    (``write_rows``), ``W_kvb`` absorbed into the query and the output.
+    pos: (B,)."""
     b = x.shape[0]
     h, nope, r = cfg.n_heads, cfg.qk_nope_head_dim, cfg.kv_lora_rank
     q_nope, q_pe, new = _project(p, x, cfg, mla_rope(cfg, pos[:, None]))
-    size = cache["latent"].shape[1]
+    size = cache["latent"].shape[-2]
     slot = (pos % size).astype(jnp.int32)
-    latent = jax.vmap(lambda c, n, i: jax.lax.dynamic_update_slice(
-        c, n, (i, 0)))(cache["latent"], new, slot)
+    stack, latent = write_rows(cache["latent"], new[:, 0], slot, layer)
     w_kvb = p["wkv_b"].reshape(r, h, -1)
     q_lat = jnp.einsum("bhn,rhn->bhr", q_nope[:, 0], w_kvb[..., :nope],
                        preferred_element_type=jnp.float32).astype(x.dtype)
@@ -109,7 +112,7 @@ def apply_mla_decode(p: dict, x: jax.Array, cfg: ArchConfig, cache: dict,
         1.0 / math.sqrt(nope + cfg.qk_rope_head_dim))
     out = jnp.einsum("bhr,rhv->bhv", o_lat, w_kvb[..., nope:],
                      preferred_element_type=jnp.float32).astype(x.dtype)
-    return out.reshape(b, 1, -1) @ p["wo"], {"latent": latent}
+    return out.reshape(b, 1, -1) @ p["wo"], {"latent": stack}
 
 
 def latent_decode_attention(q_lat: jax.Array, q_pe: jax.Array,

@@ -36,7 +36,8 @@ from ..configs.base import ArchConfig
 from ..sharding.ctx import constrain
 from . import mla, ssm
 from .layers import (apply_rope, causal_attention_ref, decode_attention_ref,
-                     dense_init, repeat_kv, rms_norm, rope_tables)
+                     dense_init, repeat_kv, rms_norm, rope_tables,
+                     write_rows)
 from .moe import apply_moe, init_moe
 
 LOSS_CHUNK = 1024
@@ -166,10 +167,13 @@ def apply_attn_seq(p: dict, x: jax.Array, cfg: ArchConfig,
 
 
 def apply_attn_decode(p: dict, x: jax.Array, cfg: ArchConfig,
-                      cache: dict, pos: jax.Array) -> tuple[jax.Array, dict]:
+                      cache: dict, pos: jax.Array,
+                      layer: jax.Array) -> tuple[jax.Array, dict]:
     """One-token decode against a (possibly ring-buffered SWA) KV cache.
 
-    cache: {"k": (B, C, Hkv, hd), "v": ...}; C = min(S_max, window).
+    cache: every layer's, {"k": (L, B, C, Hkv, hd), "v": ...},
+    C = min(S_max, window); this layer (index ``layer``) writes its new
+    rows in place (``write_rows``) and attends over its own part.
     pos: (B,) absolute position of the new token.
     """
     b, s, _ = x.shape
@@ -178,18 +182,16 @@ def apply_attn_decode(p: dict, x: jax.Array, cfg: ArchConfig,
     rope = rope_tables(pos[:, None], cfg.hd, cfg.rope_theta)
     q = apply_rope(q, rope)
     k = apply_rope(k, rope)
-    cache_size = cache["k"].shape[1]
+    cache_size = cache["k"].shape[-3]
     slot = (pos % cache_size).astype(jnp.int32)
-    k_cache = jax.vmap(lambda c, kk, i: jax.lax.dynamic_update_slice(
-        c, kk, (i, 0, 0)))(cache["k"], k, slot)
-    v_cache = jax.vmap(lambda c, vv, i: jax.lax.dynamic_update_slice(
-        c, vv, (i, 0, 0)))(cache["v"], v, slot)
+    k_stack, k_cache = write_rows(cache["k"], k[:, 0], slot, layer)
+    v_stack, v_cache = write_rows(cache["v"], v[:, 0], slot, layer)
     cache_len = jnp.minimum(pos + 1, cache_size)
     # ring buffer holds exactly the window; mask by valid slot count only.
     # GQA handled inside (no repeat_kv: §Perf iteration 5b).
     out = decode_attention_ref(q, k_cache, v_cache, cache_len, window=None)
     out = out.reshape(b, 1, cfg.n_heads * cfg.hd) @ p["wo"]
-    return out, {"k": k_cache, "v": v_cache}
+    return out, {"k": k_stack, "v": v_stack}
 
 
 # =============================================================== blocks
@@ -234,39 +236,53 @@ def apply_block_seq(lp: dict, x: jax.Array, cfg: ArchConfig,
     return x, aux, cache
 
 
+def _at_layer(stack, layer: jax.Array):
+    return jax.tree.map(lambda s: s[layer], stack)
+
+
+def _set_layer(stack, layer: jax.Array, new):
+    return jax.tree.map(lambda s, n: s.at[layer].set(n), stack, new)
+
+
 def apply_block_decode(lp: dict, x: jax.Array, cfg: ArchConfig,
-                       cache: dict, pos: jax.Array):
-    """One layer for one decode token. Returns (x, new_cache)."""
+                       cache: dict, pos: jax.Array, layer: jax.Array):
+    """One layer for one decode token. ``cache`` holds every layer's
+    stacked state; this layer (index ``layer``) writes one new row into
+    each sequence cache and its own slice of the other states. Returns
+    (x, cache)."""
     if cfg.attn_free:
         normed = rms_norm(x, lp["ln1"], cfg.norm_eps)
         h, tstate = ssm.apply_rwkv_tmix(lp["tmix"], normed, cfg,
-                                        state=cache["tmix"])
+                                        state=_at_layer(cache["tmix"], layer))
         x = x + h
         normed = rms_norm(x, lp["ln2"], cfg.norm_eps)
         h, cstate = ssm.apply_rwkv_cmix(lp["cmix"], normed, cfg,
-                                        state=cache["cmix"])
+                                        state=_at_layer(cache["cmix"], layer))
         x = x + h
-        return x, {"tmix": tstate, "cmix": cstate}
+        return x, {"tmix": _set_layer(cache["tmix"], layer, tstate),
+                   "cmix": _set_layer(cache["cmix"], layer, cstate)}
     normed = rms_norm(x, lp["ln1"], cfg.norm_eps)
     if cfg.is_mla:
         attn_out, kv = mla.apply_mla_decode(lp["attn"], normed, cfg, cache,
-                                            pos)
+                                            pos, layer)
     else:
         attn_out, kv = apply_attn_decode(lp["attn"], normed, cfg,
-                                         cache["kv"], pos)
-    new_cache = _attn_cache(cfg, kv)
+                                         cache["kv"], pos, layer)
+    new_cache = {**cache, **_attn_cache(cfg, kv)}
     if cfg.hybrid_ssm:
         ssm_out, mstate = ssm.apply_mamba(lp["mamba"], normed, cfg,
-                                          state=cache["mamba"])
+                                          state=_at_layer(cache["mamba"],
+                                                          layer))
         x = x + 0.5 * (attn_out + ssm_out)
-        new_cache["mamba"] = mstate
+        new_cache["mamba"] = _set_layer(cache["mamba"], layer, mstate)
     else:
         x = x + attn_out
     normed2 = rms_norm(x, lp["ln2"], cfg.norm_eps)
     if cfg.is_moe:
         b, s, d = normed2.shape
         out, _, counts = apply_moe(lp["moe"], normed2.reshape(b * s, d), cfg)
-        new_cache["routed"] = jax.tree.map(jnp.add, cache["routed"], counts)
+        new_cache["routed"] = jax.tree.map(lambda c, n: c.at[layer].add(n),
+                                           cache["routed"], counts)
         x = x + out.reshape(b, s, d)
     else:
         m = lp["mlp"]
@@ -396,13 +412,14 @@ SEQUENCE_CACHES = frozenset({"k", "v", "latent"})
 
 def grow_decode_cache(caches: dict, n: int) -> dict:
     """``caches`` with room for ``n`` more positions in each sequence
-    cache."""
+    cache. A layer at a time, so that a change of layout on the way (into
+    the decode step's) needs room for one layer's cache, not the stack's."""
     def grow(path, c):
         if getattr(path[-1], "key", None) not in SEQUENCE_CACHES:
             return c
-        pad = [(0, 0)] * c.ndim
-        pad[2] = (0, n)
-        return jnp.pad(c, pad)
+        pad = [(0, 0)] * (c.ndim - 1)
+        pad[1] = (0, n)
+        return jax.lax.map(lambda layer: jnp.pad(layer, pad), c)
     return jax.tree_util.tree_map_with_path(grow, caches)
 
 
@@ -463,14 +480,19 @@ def decode_step(params: dict, cfg: ArchConfig, tokens: jax.Array,
     else:
         x = params["embed"][tokens][:, None, :]
 
+    # Each run of layers carries its stacked caches through the scan, so
+    # each layer writes its new rows in place and no step copies a whole
+    # cache (the engine also keeps them in the layout the compiler picks).
     new_caches = {}
     for name, seg in segments(cfg):
-        def body(x, lp_cache, seg=seg):
-            lp, cache = lp_cache
-            return apply_block_decode(lp, x, seg, cache, pos)
+        def body(carry, lp_layer, seg=seg):
+            x, cache = carry
+            lp, layer = lp_layer
+            return apply_block_decode(lp, x, seg, cache, pos, layer), None
 
-        x, new_caches[name] = jax.lax.scan(body, x,
-                                           (params[name], caches[name]))
+        (x, new_caches[name]), _ = jax.lax.scan(
+            body, (x, caches[name]),
+            (params[name], jnp.arange(seg.n_layers)))
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = (x[:, 0, :] @ lm_head_weight(params, cfg)).astype(jnp.float32)
     return logits, new_caches

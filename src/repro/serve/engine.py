@@ -6,11 +6,14 @@ zero-roundtrip reads."""
 from __future__ import annotations
 
 from dataclasses import dataclass
+from functools import partial
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.compilation_cache import compilation_cache
+from jax.experimental.layout import Format, Layout
 from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from ..configs.base import ArchConfig
@@ -52,10 +55,14 @@ class Engine:
         # One compiled program per phase and shape, built once. The decode
         # step also advances the positions (and, in the caches, the expert
         # counts), and its new caches take the buffers of the caches it is
-        # given. Every program in flight holds one of the device queue's
-        # slots, so a step issues two (decode and sample) and the host
-        # runs that much further ahead.
+        # given, in the layout the compiler picks for them: the layer scan
+        # carries them and writes one row a layer in place, and with the
+        # layout fixed from outside XLA would relay the whole cache out on
+        # entering and leaving that loop. Every program in flight holds one
+        # of the device queue's slots, so a step issues two (decode and
+        # sample) and the host runs that much further ahead.
         temperature = serve_cfg.temperature
+        auto = Format(Layout.AUTO)
 
         def serve_prefill(params, tokens):
             return prefill(params, cfg, {"tokens": tokens})
@@ -70,8 +77,11 @@ class Engine:
                                           axis=-1).astype(jnp.int32)
 
         self._prefill = jax.jit(serve_prefill)
-        self._decode = jax.jit(serve_decode, donate_argnums=2)
+        self._decode = jax.jit(serve_decode, donate_argnums=2,
+                               in_shardings=(None, None, auto, None),
+                               out_shardings=(None, auto, None))
         self._pick = jax.jit(serve_sample)
+        self._compiled: dict = {}
 
     def generate(self, tokens: jax.Array,
                  max_new_tokens: Optional[int] = None,
@@ -91,7 +101,6 @@ class Engine:
         programs, so after the first batch of a shape nothing is traced
         or compiled again. Nothing is read from the device before the
         fetch: a step waits only while the device's queue is full."""
-        cfg = self.cfg
         b, s = tokens.shape
         n_new = max_new_tokens or self.scfg.max_new_tokens
         with TraceAnnotation("engine.generate", batch=b, prompt_len=s,
@@ -100,10 +109,10 @@ class Engine:
                 logits, caches, pos = self._prefill(self.params, tokens)
                 # a copy: the decode steps take over the caches' buffers
                 prefilled = expert_counts(caches)
-            # grow the sequence caches to hold the generated tokens
-            if not cfg.attn_free:
-                with TraceAnnotation("engine.grow_cache"):
-                    caches = grow_decode_cache(caches, n_new)
+            with TraceAnnotation("engine.grow_cache"):
+                # room for the generated tokens, in the decode step's layout
+                decode, grow = self._programs(pos, caches, pos, n_new)
+                caches = grow(caches)
             out, seen = [], []
             key = jax.random.PRNGKey(self.scfg.seed)
             tok = self._sample(logits, key)
@@ -112,8 +121,8 @@ class Engine:
                 seen.append(logits)
             for i in range(n_new - 1):
                 with StepTraceAnnotation("engine.decode_step", step_num=i):
-                    logits, caches, pos = self._decode(self.params, tok,
-                                                       caches, pos)
+                    logits, caches, pos = decode(self.params, tok, caches,
+                                                 pos)
                     if self.scfg.temperature > 0.0:   # greedy needs no key
                         key = jax.random.fold_in(key, i)
                     tok = self._sample(logits, key)
@@ -129,6 +138,29 @@ class Engine:
                 if return_logits:
                     return ids, np.stack(seen, axis=1)
             return ids
+
+    def _programs(self, tok, caches, pos, n_new: int):
+        """The decode step compiled for the arguments of a batch's first
+        step, whose caches are a prefill's (arrays or
+        ``jax.ShapeDtypeStruct``s) grown by ``n_new`` positions; and,
+        compiled for those caches, the program, run once a batch, that
+        grows them into the layout the step takes them in. Both built
+        once per shape."""
+        key = (n_new, tuple((a.shape, a.dtype)
+                            for a in jax.tree.leaves((tok, caches, pos))))
+        if key not in self._compiled:
+            grow = partial(grow_decode_cache, n=n_new)
+            grown = jax.tree.map(
+                lambda g, c: jax.ShapeDtypeStruct(g.shape, g.dtype,
+                                                  sharding=c.sharding),
+                jax.eval_shape(grow, caches), caches)
+            decode = _compile_anew(self._decode.lower(self.params, tok,
+                                                      grown, pos))
+            grow = _compile_anew(jax.jit(
+                grow, out_shardings=decode.input_formats[0][2]
+            ).lower(caches))
+            self._compiled[key] = decode, grow
+        return self._compiled[key]
 
     def _count(self, prefilled: dict, total: dict) -> None:
         if not total:
@@ -150,3 +182,19 @@ class Engine:
     def _sample(self, logits: jax.Array, key) -> jax.Array:
         with TraceAnnotation("engine.sample"):
             return self._pick(logits, key)
+
+
+def _compile_anew(lowered):
+    """``lowered`` compiled, and never read back from JAX's persistent
+    compilation cache: on a TPU v5e an executable read back from it
+    returns its outputs in the default layout, not the one it was
+    compiled for, so a program that hands arrays on in a layout of its
+    own is compiled anew in each process."""
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        return lowered.compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()

@@ -1,8 +1,10 @@
 """The serving engine runs compiled programs: after the first batch of a
 shape, a further batch traces, lowers and compiles nothing; the output
-is that of the plain eager prefill and decode steps; and the decode step
-takes over the buffers of the caches it is given."""
+is that of the plain eager prefill and decode steps, for every kind of
+cache; and the decode step takes over the buffers of the caches it is
+given."""
 
+import dataclasses
 from collections import Counter
 
 import jax
@@ -12,7 +14,7 @@ import pytest
 
 from repro.configs import get_arch
 from repro.launch.train import PRESETS
-from repro.models import decode_step, init_params, prefill
+from repro.models import decode_step, grow_decode_cache, init_params, prefill
 from repro.serve.engine import Engine, ServeConfig
 
 TINY = PRESETS["tiny"]
@@ -24,12 +26,15 @@ def _prompts(cfg, batch=2, length=8):
                               cfg.vocab_size)
 
 
-@pytest.mark.parametrize("arch", ["h2o-danube-1.8b", "rwkv6-3b",
-                                  "hymba-1.5b"])
+# Dense with a sliding window, attention-free, hybrid, and latent
+# attention with a held share of the experts.
+ARCHS = ["h2o-danube-1.8b", "rwkv6-3b", "hymba-1.5b", "moonlight-16b-a3b"]
+
+
+@pytest.mark.parametrize("arch", ARCHS)
 def test_second_batch_compiles_nothing(arch):
-    """Dense with a sliding window, attention-free and hybrid: one batch
-    warms the programs up, a second of the same shape is served from
-    them."""
+    """One batch warms the programs up, a second of the same shape is
+    served from them."""
     cfg = get_arch(arch).reduced()
     engine = Engine(cfg, init_params(jax.random.PRNGKey(0), cfg),
                     ServeConfig(max_new_tokens=NEW_TOKENS))
@@ -54,9 +59,7 @@ def test_second_batch_compiles_nothing(arch):
 def _eager_greedy(params, cfg, prompts, n_new):
     """Greedy decoding by the plain eager prefill and decode steps."""
     logits, caches, pos = prefill(params, cfg, {"tokens": prompts})
-    caches = jax.tree.map(
-        lambda c: jnp.pad(c, [(0, 0), (0, 0), (0, n_new), (0, 0), (0, 0)]),
-        caches)
+    caches = grow_decode_cache(caches, n_new)
     ids, seen = [], [logits]
     tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     ids.append(tok)
@@ -86,11 +89,67 @@ def test_compiled_programs_serve_what_the_eager_steps_do():
     np.testing.assert_array_equal(logits_again, logits)
 
 
+@pytest.mark.parametrize("arch", ARCHS)
+def test_engine_serves_what_a_plain_decode_loop_does(arch):
+    """Greedy ids, float32 weights: in bf16 a random model's best logits
+    can tie exactly, and then the order of summation picks the id."""
+    cfg = dataclasses.replace(get_arch(arch).reduced(),
+                              param_dtype="float32")
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    engine = Engine(cfg, params, ServeConfig(max_new_tokens=NEW_TOKENS))
+    ids = engine.generate(_prompts(cfg))
+    want, _ = _eager_greedy(params, cfg, _prompts(cfg), NEW_TOKENS)
+    np.testing.assert_array_equal(ids, want)
+
+
 def test_decode_step_takes_over_the_cache_buffers():
     params = init_params(jax.random.PRNGKey(0), TINY)
     engine = Engine(TINY, params, ServeConfig(max_new_tokens=NEW_TOKENS))
     logits, caches, pos = engine._prefill(params, _prompts(TINY))
     tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    engine._decode(params, tok, caches, pos)
-    assert all(c.is_deleted() for c in jax.tree.leaves(caches))
+    decode, grow = engine._programs(tok, caches, pos, NEW_TOKENS)
+    grown = grow(caches)
+    decode(params, tok, grown, pos)
+    assert all(c.is_deleted() for c in jax.tree.leaves(grown))
     assert not any(p.is_deleted() for p in jax.tree.leaves(params))
+
+
+def test_layout_programs_are_compiled_anew(tmp_path):
+    """The decode step and the cache growth hand caches on in a layout of
+    their own, which an executable read back from the persistent
+    compilation cache loses on a TPU: a second engine compiles them anew
+    while its prefill comes back from the cache."""
+    from jax.experimental.compilation_cache import compilation_cache
+    params = init_params(jax.random.PRNGKey(0), TINY)
+    hits = Counter()
+
+    def count(event, **_kw):
+        if event == "/jax/compilation_cache/cache_hits":
+            hits["now"] += 1
+
+    saved = {name: getattr(jax.config, name) for name in (
+        "jax_enable_compilation_cache", "jax_compilation_cache_dir",
+        "jax_persistent_cache_min_compile_time_secs",
+        "jax_persistent_cache_min_entry_size_bytes")}
+    jax.config.update("jax_enable_compilation_cache", True)
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    compilation_cache.reset_cache()
+    jax.monitoring.register_event_listener(count)
+    try:
+        for _ in range(2):
+            engine = Engine(TINY, params,
+                            ServeConfig(max_new_tokens=NEW_TOKENS))
+            hits.clear()
+            logits, caches, pos = engine._prefill(params, _prompts(TINY))
+            prefill_hits = hits["now"]
+            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            engine._programs(tok, caches, pos, NEW_TOKENS)
+        assert prefill_hits > 0           # the cache is on and read
+        assert hits["now"] == prefill_hits, dict(hits)
+    finally:
+        jax.monitoring.unregister_event_listener(count)
+        for name, value in saved.items():
+            jax.config.update(name, value)
+        compilation_cache.reset_cache()

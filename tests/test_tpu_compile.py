@@ -128,6 +128,28 @@ SCOPES = {"prefill": "vmemkernel_flash_attention",
           "decode": "vmemkernel_decode_attention"}
 
 
+def _decode_programs(served, one_chip):
+    """The engine's decode step and cache growth for a served shape,
+    compiled as Engine.generate builds them, and the decode caches'
+    shapes."""
+    arch, held, batch, prompt, cache_len, _ = SERVED[served]
+    cfg = dataclasses.replace(get_arch(arch), experts_held=held)
+    params = _on_chip(jax.eval_shape(
+        partial(init_params, jax.random.PRNGKey(0), cfg)), one_chip)
+    engine = Engine(cfg, params)
+    prefilled = _on_chip(jax.eval_shape(
+        partial(init_decode_cache, cfg, batch, prompt)), one_chip)
+    ids = jax.ShapeDtypeStruct((batch,), I32, sharding=one_chip)
+    decode, grow = engine._programs(ids, prefilled, ids, cache_len - prompt)
+    caches = jax.eval_shape(partial(init_decode_cache, cfg, batch,
+                                    cache_len))
+    return decode, grow, caches
+
+
+def _bytes(tree) -> int:
+    return sum(c.size * c.dtype.itemsize for c in jax.tree.leaves(tree))
+
+
 @pytest.mark.parametrize("program", ["prefill", "decode"])
 @pytest.mark.parametrize("served", sorted(SERVED))
 def test_engine_program_fits_one_v5e(served, program, one_chip):
@@ -136,23 +158,18 @@ def test_engine_program_fits_one_v5e(served, program, one_chip):
     memory, and the decode step's new caches take the donated caches'
     buffers."""
     arch, held, batch, prompt, cache_len, weight_bytes = SERVED[served]
-    cfg = dataclasses.replace(get_arch(arch), experts_held=held)
-    params = _on_chip(jax.eval_shape(
-        partial(init_params, jax.random.PRNGKey(0), cfg)), one_chip)
-    engine = Engine(cfg, params)
     if program == "prefill":
+        cfg = dataclasses.replace(get_arch(arch), experts_held=held)
+        params = _on_chip(jax.eval_shape(
+            partial(init_params, jax.random.PRNGKey(0), cfg)), one_chip)
         tokens = jax.ShapeDtypeStruct((batch, prompt), I32,
                                       sharding=one_chip)
-        compiled = engine._prefill.lower(params, tokens).compile()
+        compiled = Engine(cfg, params)._prefill.lower(params,
+                                                      tokens).compile()
     else:
-        caches = _on_chip(jax.eval_shape(
-            partial(init_decode_cache, cfg, batch, cache_len)), one_chip)
-        ids = jax.ShapeDtypeStruct((batch,), I32, sharding=one_chip)
-        compiled = engine._decode.lower(params, ids, caches, ids).compile()
-        cache_bytes = sum(c.size * c.dtype.itemsize
-                          for c in jax.tree.leaves(caches))
+        compiled, _, caches = _decode_programs(served, one_chip)
         assert compiled.memory_analysis().alias_size_in_bytes \
-            >= cache_bytes
+            >= _bytes(caches)
     # the benchmark's readers find each program by its attention's scope
     assert SCOPES[program] in compiled.as_text()
     mem = compiled.memory_analysis()
@@ -160,3 +177,27 @@ def test_engine_program_fits_one_v5e(served, program, one_chip):
     assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
             - mem.alias_size_in_bytes + mem.temp_size_in_bytes) \
         < V5E_HBM_BYTES
+
+
+@pytest.mark.parametrize("served", sorted(SERVED))
+def test_decode_step_updates_the_caches_in_place(served, one_chip):
+    """The decode step writes its rows into the caches it is given: next
+    to no scratch, no copy of a whole stacked cache (as a layer scan that
+    returns new caches, or a change of their layout, would make), and the
+    caches come back in the layout they went in, in which the growth
+    program hands them over, itself with next to no scratch."""
+    decode, grow, caches = _decode_programs(served, one_chip)
+    cache_bytes = _bytes(caches)
+    assert decode.memory_analysis().temp_size_in_bytes < 0.05 * cache_bytes
+    assert grow.memory_analysis().temp_size_in_bytes < 0.05 * cache_bytes
+    stacks = {"[" + ",".join(map(str, c.shape)) + "]"
+              for c in jax.tree.leaves(caches) if c.ndim >= 4}
+    copies = [line.split(" = ")[1].split(" ")[0]
+              for line in decode.as_text().splitlines()
+              if " copy(" in line or " copy-start(" in line]
+    assert not [c for c in copies if any(c.endswith(s) or s + "{" in c
+                                         for s in stacks)], copies
+    takes = decode.input_formats[0][2]
+    assert jax.tree.leaves(decode.output_formats[1]) == \
+        jax.tree.leaves(takes)
+    assert jax.tree.leaves(grow.output_formats) == jax.tree.leaves(takes)

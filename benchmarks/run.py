@@ -95,9 +95,6 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--only", default=None)
-    ap.add_argument("--roofline", action="store_true",
-                    help="also run the data-plane roofline benchmark "
-                         "(slow: compiles dry-run cells)")
     args = ap.parse_args()
 
     summary = []
@@ -110,15 +107,6 @@ def main() -> None:
         dt = time.time() - t0
         emit(rows)
         summary.append((name, dt * 1e6 / max(1, len(rows)), len(rows)))
-
-    if args.roofline:
-        from . import roofline_bench
-        print("\n== roofline ==", flush=True)
-        t0 = time.time()
-        rows = roofline_bench.run(quick=args.quick)
-        dt = time.time() - t0
-        emit(rows)
-        summary.append(("roofline", dt * 1e6 / max(1, len(rows)), len(rows)))
 
     print("\nname,us_per_call,derived")
     for name, us, n in summary:

@@ -1,7 +1,6 @@
 """Model-zoo registry: ``--arch <id>`` resolves here."""
 
-from .base import (ALL_SHAPES, DECODE_32K, LONG_500K, PREFILL_32K, TRAIN_4K,
-                   ArchConfig, ShapeConfig, shape_applicable)
+from .base import ArchConfig, ShapeConfig
 from .arctic_480b import CONFIG as ARCTIC_480B
 from .h2o_danube_1_8b import CONFIG as H2O_DANUBE_1_8B
 from .hymba_1_5b import CONFIG as HYMBA_1_5B
@@ -21,8 +20,6 @@ ARCHS: dict[str, ArchConfig] = {
     ]
 }
 
-SHAPES: dict[str, ShapeConfig] = {s.name: s for s in ALL_SHAPES}
-
 
 def get_arch(name: str) -> ArchConfig:
     if name not in ARCHS:
@@ -30,12 +27,4 @@ def get_arch(name: str) -> ArchConfig:
     return ARCHS[name]
 
 
-def get_shape(name: str) -> ShapeConfig:
-    if name not in SHAPES:
-        raise KeyError(f"unknown shape {name!r}; known: {sorted(SHAPES)}")
-    return SHAPES[name]
-
-
-__all__ = ["ARCHS", "SHAPES", "ArchConfig", "ShapeConfig", "get_arch",
-           "get_shape", "shape_applicable", "ALL_SHAPES", "TRAIN_4K",
-           "PREFILL_32K", "DECODE_32K", "LONG_500K"]
+__all__ = ["ARCHS", "ArchConfig", "ShapeConfig", "get_arch"]

@@ -41,7 +41,6 @@ class ArchConfig:
     first_k_dense: int = 0                    # leading dense layers
     dense_d_ff: int = 0                       # their SwiGLU width
     moe_dense_residual: bool = False          # arctic: parallel dense FFN
-    capacity_factor: float = 1.25             # group-local dispatch only
 
     # SSM / hybrid
     attn_free: bool = False                   # rwkv6
@@ -107,8 +106,6 @@ class ArchConfig:
             qk_nope_head_dim=16 if mla else 0,
             qk_rope_head_dim=8 if mla else 0,
             v_head_dim=16 if mla else 0,
-            # drop-free capacity so prefill/decode agree exactly in tests
-            capacity_factor=float(max(1, self.n_experts)),
             sliding_window=16 if self.sliding_window else None,
             ssm_state=min(self.ssm_state, 8) if self.ssm_state else 0,
             rwkv_head_dim=16 if self.attn_free else self.rwkv_head_dim,
@@ -172,28 +169,3 @@ class ShapeConfig:
     kind: str                    # train | prefill | decode
     seq_len: int
     global_batch: int
-
-    @property
-    def is_decode(self) -> bool:
-        return self.kind == "decode"
-
-
-TRAIN_4K = ShapeConfig("train_4k", "train", 4096, 256)
-PREFILL_32K = ShapeConfig("prefill_32k", "prefill", 32768, 32)
-DECODE_32K = ShapeConfig("decode_32k", "decode", 32768, 128)
-LONG_500K = ShapeConfig("long_500k", "decode", 524288, 1)
-
-ALL_SHAPES = [TRAIN_4K, PREFILL_32K, DECODE_32K, LONG_500K]
-
-
-def shape_applicable(arch: ArchConfig, shape: ShapeConfig) -> tuple[bool, str]:
-    """long_500k needs sub-quadratic attention / bounded state (DESIGN.md
-    §Arch-applicability)."""
-    if shape.name == "long_500k":
-        sub_quadratic = arch.attn_free or arch.hybrid_ssm or \
-            (arch.sliding_window is not None)
-        if not sub_quadratic:
-            return False, ("pure full-attention arch: 500k-context decode "
-                           "requires sub-quadratic attention (skip noted in "
-                           "DESIGN.md)")
-    return True, ""

@@ -13,6 +13,6 @@ CONFIG = ArchConfig(
     d_ff=6912,
     vocab_size=32000,
     sliding_window=4096,
-    grad_accum=2,      # SWA: KV bounded by the window => long_500k ok
+    grad_accum=2,
     source="arXiv:2401.16818",
 )

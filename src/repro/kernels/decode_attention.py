@@ -3,8 +3,7 @@
 GQA-native: the query block is the GROUP of query heads sharing one KV
 head — (grp, hd) lives in registers while the kernel streams the cache in
 (block_s, hd) VMEM tiles with online softmax. HBM traffic = K + V read
-once + (grp, hd) out; the XLA reference materializes (grp, S) scores and
-(after GSPMD) broadcasts repeated KV in f32 (§Perf iteration 5b).
+once + (grp, hd) out; the XLA reference materializes (grp, S) scores.
 
 Grid = (B·Hkv, S/block_s), cache-block dim minormost so the (grp, hd)
 accumulator persists in VMEM scratch across cache blocks. Invalid slots

@@ -8,8 +8,7 @@ k_blocks) iterated sequentially per core with VMEM-resident accumulators
 dimension — the Pallas/TPU analogue of a persistent-CTA flash kernel.
 Block shapes are MXU-aligned (q/k blocks multiples of 128 where the
 sequence allows; head_dim padded by the caller when < 128 is needed).
-Scores and probabilities live only in VMEM: HBM traffic is Q+K+V+O, which
-is what the roofline's kernel-adjusted memory term assumes.
+Scores and probabilities live only in VMEM: HBM traffic is Q+K+V+O.
 
 Safety: k_blocks that are fully masked (causal/window) contribute nothing;
 they are computed-and-masked rather than skipped, keeping the kernel

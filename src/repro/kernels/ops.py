@@ -3,9 +3,7 @@
 ``impl`` selects the path:
   * "pallas"            real Mosaic lowering (TPU runtime)
   * "pallas_interpret"  kernel body executed on CPU (correctness tests)
-  * "reference"         pure-jnp oracle (dry-run lowering; the roofline
-                        analyzer's vmemkernel_* scopes account for the
-                        VMEM-residency the Pallas path provides on TPU)
+  * "reference"         pure-jnp oracle the kernels are tested against
 """
 
 from __future__ import annotations

@@ -3,9 +3,8 @@
 Conventions:
 * params are nested dicts of jnp arrays; compute dtype bf16, accumulation
   and norms in fp32;
-* attention projections are kept FLAT — (d_model, n_heads*head_dim) — so
-  tensor-parallel sharding divides the flattened dim regardless of head
-  count (heads are reshaped after the matmul);
+* attention projections are kept flat — (d_model, n_heads*head_dim) —
+  and heads are reshaped after the matmul;
 * the causal-attention reference is **chunked** over queries (bounded
   memory: never materializes the full S×S score matrix), which is also the
   oracle for the Pallas flash-attention kernel.
@@ -92,14 +91,12 @@ def causal_attention_ref(q: jax.Array, k: jax.Array, v: jax.Array,
     @partial(jax.checkpoint, prevent_cse=False)
     def attend(q_chunk: jax.Array, qpos: jax.Array) -> jax.Array:
         # q_chunk: (B, C, H, hd); qpos: (C,)
-        # named_scope marks this region VMEM-resident on the TPU target:
-        # the Pallas flash kernel keeps scores/probs in VMEM, so the
-        # roofline analyzer buckets this region's HBM traffic separately
-        # (see repro/roofline.py and kernels/flash_attention.py).
+        # the scope marks the region whose device time the chip
+        # benchmark's prefill_attn_roofline.serve divides by; a kernel
+        # that replaces this code keeps the name
         with jax.named_scope("vmemkernel_flash_attention"):
-            # bf16 inputs, f32 accumulation (MXU-native): cotangents stay
-            # bf16, so the TP gradient all-reduces cross the mesh in bf16
-            # (§Perf iteration 2 — halves collective bytes vs f32 casts)
+            # bf16 inputs, f32 accumulation (MXU-native); the cotangents
+            # stay bf16
             s = jnp.einsum("bqhd,bkhd->bhqk", q_chunk, k,
                            preferred_element_type=jnp.float32) * scale
             mask = qpos[:, None] >= kpos[None, :]
@@ -128,9 +125,8 @@ def decode_attention_ref(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                          cache_len: jax.Array,
                          window: Optional[int] = None) -> jax.Array:
     """Single-step GQA decode. q: (B,1,H,hd); caches: (B,Smax,Hkv,hd) —
-    NOT repeated: query heads are grouped onto their shared KV head
-    (§Perf iteration 5b: the repeat_kv broadcast was the dominant decode
-    collective/traffic — an f32 all-gather of the whole cache).
+    NOT repeated: query heads are grouped onto their shared KV head, so
+    the cache is read once, never broadcast per query head.
     ``cache_len``: #valid entries incl. the new token."""
     b, _, h, hd = q.shape
     hkv = k_cache.shape[2]

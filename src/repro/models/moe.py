@@ -13,14 +13,9 @@ part of the experts held elsewhere is left to their chips. Shared experts
 (one SwiGLU of ``n_shared_experts * d_ff``) and arctic's parallel dense
 FFN see every token.
 
-Dispatch layouts (``repro.sharding.ctx.moe_groups()`` selects):
-* sorted (1 group, the default): the (token, expert) pairs are sorted by
-  held expert and run through grouped products (``jax.lax.ragged_dot``);
-  pairs of experts held elsewhere sort last and are not computed. No
-  token is dropped.
-* group-local (n_groups = dp extent): GShard capacity buffers per group
-  (§Perf iteration 6, measured worse under GSPMD; kept for a shard_map
-  follow-up). Pairs beyond a group's capacity are dropped.
+Dispatch: the (token, expert) pairs are sorted by held expert and run
+through grouped products (``jax.lax.ragged_dot``); pairs of experts held
+elsewhere sort last and are not computed. No token is dropped.
 """
 
 from __future__ import annotations
@@ -29,7 +24,6 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import ArchConfig
-from ..sharding.ctx import constrain
 from .layers import dense_init
 
 # Tokens dispatched at once: prefill runs its tokens through the sorted
@@ -66,9 +60,9 @@ def _init_swiglu(key, d: int, f: int, dtype) -> dict:
 
 
 def _swiglu(p: dict, x: jax.Array) -> jax.Array:
-    g = constrain(jnp.dot(x, p["w_gate"]), "dp", "tp")
-    u = constrain(jnp.dot(x, p["w_up"]), "dp", "tp")
-    return constrain(jnp.dot(jax.nn.silu(g) * u, p["w_down"]), "dp", None)
+    g = jnp.dot(x, p["w_gate"])
+    u = jnp.dot(x, p["w_up"])
+    return jnp.dot(jax.nn.silu(g) * u, p["w_down"])
 
 
 def route(p: dict, x: jax.Array, cfg: ArchConfig):
@@ -100,13 +94,9 @@ def apply_moe(p: dict, x: jax.Array, cfg: ArchConfig, first: int = 0
     experts' part plus the shared and dense parts, the load-balancing
     loss, and counts: ``expert_tokens`` (held,), the tokens routed to each
     held expert, and ``expert_loads``, how many held experts had any."""
-    from ..sharding.ctx import moe_groups
     t = x.shape[0]
     gate, idx, aux = route(p, x, cfg)
-    groups = moe_groups()
-    if groups > 1 and t % groups == 0:
-        out, sizes = _dispatch_grouped(p, x, gate, idx - first, cfg, groups)
-    elif t > DISPATCH_CHUNK and t % DISPATCH_CHUNK == 0:
+    if t > DISPATCH_CHUNK and t % DISPATCH_CHUNK == 0:
         n = t // DISPATCH_CHUNK
         out, sizes = jax.lax.map(
             lambda a: _dispatch_sorted(p, *a, cfg),
@@ -157,55 +147,3 @@ def _dispatch_sorted(p: dict, x: jax.Array, gate: jax.Array,
             jnp.arange(order.shape[0], dtype=order.dtype))
         out = jnp.sum(y[back].reshape(t, k, d), axis=1).astype(x.dtype)
     return out, sizes
-
-
-def _dispatch_grouped(p: dict, x: jax.Array, gate: jax.Array,
-                      local: jax.Array, cfg: ArchConfig, groups: int):
-    """Group-local dispatch (§Perf iteration 6): the token axis is split
-    into ``groups`` contiguous slices aligned with the `data` sharding;
-    each group has a private capacity slice of every held expert, so the
-    dispatch scatter and combine gather touch only group-local rows."""
-    t, d = x.shape
-    k, held = cfg.experts_per_token, cfg.held
-    tg = t // groups
-
-    cap_g = max(1, int(cfg.capacity_factor * tg * k / cfg.n_experts))
-    flat_e = local.reshape(groups, tg * k)                      # (G, Tg*k)
-    here = (flat_e >= 0) & (flat_e < held)
-    flat_e = jnp.where(here, flat_e, 0)
-    onehot = jax.nn.one_hot(flat_e, held, dtype=jnp.int32) \
-        * here[..., None]                                       # (G, Tg*k, E)
-    sizes = jnp.sum(onehot, axis=(0, 1))
-    pos = jnp.cumsum(onehot, axis=1) * onehot                   # rank+1
-    pos = jnp.sum(pos, axis=-1) - 1                             # (G, Tg*k)
-    valid = here & (pos < cap_g)
-    pos_c = jnp.clip(pos, 0, cap_g - 1)
-
-    x_rep = jnp.repeat(x.reshape(groups, tg, d), k, axis=1)     # (G, Tg*k, d)
-    x_rep = constrain(x_rep * valid[..., None].astype(x.dtype),
-                      "dp", None, None)
-    # group-local scatter: each group writes only its own capacity slice
-    buf = jnp.zeros((groups, held, cap_g, d), x.dtype)
-    gidx = jnp.arange(groups)[:, None].repeat(tg * k, 1)        # (G, Tg*k)
-    buf = buf.at[gidx, flat_e, pos_c].add(x_rep)
-    # experts on tp, groups stay on dp END-TO-END (4-D einsums: merging
-    # (G@dp, Cg) into one dim would force GSPMD to replicate); expert
-    # weights are FSDP-sharded on their NON-contraction dim (rules.py) so
-    # the matmuls gather weights over data instead of all-reducing
-    # (E, G, Cg, f) partials
-    buf = constrain(buf.transpose(1, 0, 2, 3), "tp", "dp", None, None)
-
-    g_ = constrain(jnp.einsum("egcd,edf->egcf", buf, p["w_gate"]),
-                   "tp", "dp", None, None)
-    u_ = constrain(jnp.einsum("egcd,edf->egcf", buf, p["w_up"]),
-                   "tp", "dp", None, None)
-    h = jax.nn.silu(g_) * u_
-    out_buf = constrain(jnp.einsum("egcf,efd->egcd", h, p["w_down"]),
-                        "tp", "dp", None, None)
-    out_buf = out_buf.transpose(1, 0, 2, 3)
-
-    gathered = out_buf[gidx, flat_e, pos_c]                     # (G, Tg*k, d)
-    gathered = gathered * (gate.reshape(groups, tg * k, 1)
-                           .astype(x.dtype) * valid[..., None].astype(x.dtype))
-    out = jnp.sum(gathered.reshape(groups, tg, k, d), axis=2)
-    return constrain(out.reshape(t, d), "dp", None), sizes

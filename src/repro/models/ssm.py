@@ -1,8 +1,7 @@
 """Attention-free sequence mixers.
 
 * RWKV6 ("Finch"): token-shift + data-dependent per-channel decay, matrix
-  WKV state (head_dim × head_dim per head) — O(1) state decode, the reason
-  rwkv6-3b runs the long_500k shape.
+  WKV state (head_dim × head_dim per head) — O(1) state decode.
 * Mamba-style selective SSM head for hymba's hybrid layers (parallel
   attention + SSM in the same layer), ssm_state=16.
 
@@ -18,7 +17,6 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import ArchConfig
-from ..sharding.ctx import constrain
 from .layers import dense_init, group_norm_heads
 
 DECAY_LORA = 64
@@ -115,7 +113,7 @@ def apply_rwkv_tmix(p: dict, x: jax.Array, cfg: ArchConfig,
 
     xx = _shift(x, prev)
     mix = lambda i: x + (xx - x) * p["mu"][i].astype(x.dtype)
-    proj = lambda i, w: constrain(mix(i) @ w, "dp", None, "tp")
+    proj = lambda i, w: mix(i) @ w
     r = proj(0, p["w_r"]).reshape(b, s, h, hd)
     k = proj(1, p["w_k"]).reshape(b, s, h, hd)
     v = proj(2, p["w_v"]).reshape(b, s, h, hd)
@@ -133,7 +131,6 @@ def apply_rwkv_tmix(p: dict, x: jax.Array, cfg: ArchConfig,
     y = ys.transpose(1, 0, 2, 3)                          # (B,S,H,hd)
     y = group_norm_heads(y, p["ln_w"], p["ln_b"]).reshape(b, s, d)
     out = (y * jax.nn.silu(g).astype(y.dtype)).astype(x.dtype) @ p["w_o"]
-    out = constrain(out, "dp", "sp", None)
     new_state = {"shift": x[:, -1, :], "wkv": wkv_final}
     return out, new_state
 
@@ -144,9 +141,8 @@ def apply_rwkv_cmix(p: dict, x: jax.Array, cfg: ArchConfig,
     prev = state if state is not None else jnp.zeros((b, d), x.dtype)
     xx = _shift(x, prev)
     mix = lambda i: x + (xx - x) * p["mu"][i].astype(x.dtype)
-    k = jnp.square(jax.nn.relu(constrain(mix(0) @ p["w_k"],
-                                         "dp", None, "tp")))
-    v = constrain(k @ p["w_v"], "dp", "sp", None)
+    k = jnp.square(jax.nn.relu(mix(0) @ p["w_k"]))
+    v = k @ p["w_v"]
     r = jax.nn.sigmoid(mix(1) @ p["w_r"])
     return (r * v).astype(x.dtype), x[:, -1, :]
 
@@ -189,7 +185,7 @@ def apply_mamba(p: dict, x: jax.Array, cfg: ArchConfig,
     "h": (B,di,n)}."""
     b, s, d = x.shape
     n = cfg.ssm_state
-    xz = constrain(x @ p["in_proj"], "dp", None, None)
+    xz = x @ p["in_proj"]
     x_in, z = jnp.split(xz, 2, axis=-1)                  # (B,S,di) each
     conv_state = state["conv"] if state is not None else None
     x_c, new_conv = _causal_conv(x_in, p["conv_w"], p["conv_b"], conv_state)
@@ -218,5 +214,4 @@ def apply_mamba(p: dict, x: jax.Array, cfg: ArchConfig,
         h_final, ys = chunked_time_scan(step, h0, seq)
     y = ys.transpose(1, 0, 2) + p["d_skip"] * x_f        # (B,S,di)
     out = (y.astype(x.dtype) * jax.nn.silu(z)) @ p["out_proj"]
-    out = constrain(out, "dp", "sp", None)
     return out, {"conv": new_conv, "h": h_final}

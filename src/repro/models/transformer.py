@@ -33,7 +33,6 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import ArchConfig
-from ..sharding.ctx import constrain
 from . import mla, ssm
 from .layers import (apply_rope, causal_attention_ref, decode_attention_ref,
                      dense_init, repeat_kv, rms_norm, rope_tables,
@@ -126,22 +125,14 @@ def init_params(key: jax.Array, cfg: ArchConfig) -> dict:
 # ============================================================ attention
 def _qkv(p: dict, x: jax.Array, cfg: ArchConfig):
     b, s, _ = x.shape
-    q = constrain(x @ p["wq"], "dp", None, "tp")
-    k = constrain(x @ p["wk"], "dp", None, "tp")
-    v = constrain(x @ p["wv"], "dp", None, "tp")
+    q = x @ p["wq"]
+    k = x @ p["wk"]
+    v = x @ p["wv"]
     if cfg.qkv_bias:
         q, k, v = q + p["bq"], k + p["bk"], v + p["bv"]
-    # re-constrain per-HEAD sharding after the reshape: without this, the
-    # flat 'tp' sharding fractures heads when H % tp != 0 (arctic: 56
-    # heads / 16) and attention contracts across shards -> partial-score
-    # all-reduces (§Perf iteration 4: -15 s/step on arctic). GSPMD pads
-    # uneven head counts.
-    q = constrain(q.reshape(b, s, cfg.n_heads, cfg.hd),
-                  "dp", None, "tp", None)
-    k = constrain(k.reshape(b, s, cfg.n_kv_heads, cfg.hd),
-                  "dp", None, "tp", None)
-    v = constrain(v.reshape(b, s, cfg.n_kv_heads, cfg.hd),
-                  "dp", None, "tp", None)
+    q = q.reshape(b, s, cfg.n_heads, cfg.hd)
+    k = k.reshape(b, s, cfg.n_kv_heads, cfg.hd)
+    v = v.reshape(b, s, cfg.n_kv_heads, cfg.hd)
     if cfg.qk_norm:
         q = rms_norm(q, p["q_norm"], cfg.norm_eps)
         k = rms_norm(k, p["k_norm"], cfg.norm_eps)
@@ -160,9 +151,7 @@ def apply_attn_seq(p: dict, x: jax.Array, cfg: ArchConfig,
     n_rep = cfg.n_heads // cfg.n_kv_heads
     out = causal_attention_ref(q, repeat_kv(k, n_rep), repeat_kv(v, n_rep),
                                window=cfg.sliding_window)
-    out = constrain(out.reshape(b, s, cfg.n_heads * cfg.hd),
-                    "dp", None, "tp")
-    out = constrain(out @ p["wo"], "dp", "sp", None)
+    out = out.reshape(b, s, cfg.n_heads * cfg.hd) @ p["wo"]
     return out, {"k": k, "v": v}
 
 
@@ -188,7 +177,7 @@ def apply_attn_decode(p: dict, x: jax.Array, cfg: ArchConfig,
     v_stack, v_cache = write_rows(cache["v"], v[:, 0], slot, layer)
     cache_len = jnp.minimum(pos + 1, cache_size)
     # ring buffer holds exactly the window; mask by valid slot count only.
-    # GQA handled inside (no repeat_kv: §Perf iteration 5b).
+    # GQA is grouped inside, so the cache is never repeated per query head.
     out = decode_attention_ref(q, k_cache, v_cache, cache_len, window=None)
     out = out.reshape(b, 1, cfg.n_heads * cfg.hd) @ p["wo"]
     return out, {"k": k_stack, "v": v_stack}
@@ -210,7 +199,6 @@ def apply_block_seq(lp: dict, x: jax.Array, cfg: ArchConfig,
         x = x + h
         cache = {"tmix": tstate, "cmix": cstate}
         return x, aux, cache
-    x = constrain(x, "dp", "sp", None)   # seq-parallel residual stream
     normed = rms_norm(x, lp["ln1"], cfg.norm_eps)
     attn = mla.apply_mla_seq if cfg.is_mla else apply_attn_seq
     attn_out, kv = attn(lp["attn"], normed, cfg, rope)
@@ -220,19 +208,18 @@ def apply_block_seq(lp: dict, x: jax.Array, cfg: ArchConfig,
         x = x + 0.5 * (attn_out + ssm_out)
         cache["mamba"] = mstate
     else:
-        x = x + constrain(attn_out, "dp", "sp", None)
+        x = x + attn_out
     normed2 = rms_norm(x, lp["ln2"], cfg.norm_eps)
     if cfg.is_moe:
         b, s, d = normed2.shape
         out, aux, cache["routed"] = apply_moe(
             lp["moe"], normed2.reshape(b * s, d), cfg)
-        x = x + constrain(out.reshape(b, s, d), "dp", "sp", None)
+        x = x + out.reshape(b, s, d)
     else:
         m = lp["mlp"]
-        g = constrain(normed2 @ m["w_gate"], "dp", None, "tp")
-        u = constrain(normed2 @ m["w_up"], "dp", None, "tp")
-        x = x + constrain(jax.nn.silu(g) * u @ m["w_down"],
-                          "dp", "sp", None)
+        g = normed2 @ m["w_gate"]
+        u = normed2 @ m["w_up"]
+        x = x + jax.nn.silu(g) * u @ m["w_down"]
     return x, aux, cache
 
 
@@ -295,14 +282,13 @@ def apply_block_decode(lp: dict, x: jax.Array, cfg: ArchConfig,
 def _embed_inputs(params: dict, cfg: ArchConfig, batch: dict) -> jax.Array:
     if cfg.embedding_stub:
         # VLM/audio: precomputed patch/frame embeddings from the frontend
-        return constrain(batch["embeds"].astype(_dtype(cfg)),
-                         "dp", None, None)
-    return constrain(params["embed"][batch["tokens"]], "dp", None, None)
+        return batch["embeds"].astype(_dtype(cfg))
+    return params["embed"][batch["tokens"]]
 
 
 def _stack_layers(params: dict, cfg: ArchConfig, x: jax.Array,
                   rope: tuple, with_cache: bool,
-                  remat: bool, unroll: bool = False):
+                  remat: bool):
     """Every layer over full sequences. Returns (x, aux_loss, caches per
     segment or None)."""
     aux = jnp.zeros((), jnp.float32)
@@ -316,19 +302,7 @@ def _stack_layers(params: dict, cfg: ArchConfig, x: jax.Array,
 
         if remat:
             body = jax.checkpoint(body, prevent_cse=False)
-        if unroll:
-            # python-loop unroll (debug/validation: XLA cost_analysis
-            # counts every op; no while-loop trip ambiguity)
-            outs = []
-            carry = (x, aux)
-            for i in range(seg.n_layers):
-                lp = jax.tree.map(lambda a: a[i], params[name])
-                carry, out = body(carry, lp)
-                outs.append(out)
-            (x, aux) = carry
-            out = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
-        else:
-            (x, aux), out = jax.lax.scan(body, (x, aux), params[name])
+        (x, aux), out = jax.lax.scan(body, (x, aux), params[name])
         caches[name] = out
     return x, aux, caches if with_cache else None
 
@@ -374,8 +348,7 @@ def forward_train(params: dict, cfg: ArchConfig, batch: dict) -> jax.Array:
 
     def ce(carry, hc_lc):
         hc, lc = hc_lc
-        logits = constrain((hc @ w).astype(jnp.float32),
-                           "dp", None, "tp")             # (B, C, V)
+        logits = (hc @ w).astype(jnp.float32)             # (B, C, V)
         lse = jax.nn.logsumexp(logits, axis=-1)
         gold = jnp.take_along_axis(logits, lc[..., None], axis=-1)[..., 0]
         return carry + jnp.sum(lse - gold), None

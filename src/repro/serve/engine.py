@@ -28,9 +28,8 @@ class ServeConfig:
 
 
 class Engine:
-    """Single-host batched engine (the multi-pod serve path is lowered by
-    launch/dryrun.py with the production mesh; this class drives real
-    arrays for the examples/tests)."""
+    """Batched engine on one device: ``launch/serve.start_engine`` builds
+    it for the examples, the tests and the chip benchmark."""
 
     def __init__(self, cfg: ArchConfig, params, serve_cfg: ServeConfig =
                  ServeConfig(), registry=None,
